@@ -59,7 +59,7 @@ def run_variant(arch: str, shape_name: str, mesh_name: str, variant: str) -> dic
 
     from benchmarks.hlo_analysis import analyze_hlo
     from repro.configs.base import SHAPES, ParallelConfig, get_config
-    from repro.launch.dryrun import HW, _model_flops
+    from repro.launch.dryrun import DRYRUN_DEVICE_KIND, _model_flops, peaks
     from repro.launch.jax_compat import use_mesh
     from repro.launch.mesh import make_production_mesh
     from repro.launch.specs import abstract_caches, abstract_params, input_specs
@@ -157,12 +157,13 @@ def run_variant(arch: str, shape_name: str, mesh_name: str, variant: str) -> dic
         hlo = analyze_hlo(compiled.as_text(), pod_size=256)
 
     model_flops = _model_flops(get_config(arch), shape)
+    hw = peaks(DRYRUN_DEVICE_KIND)
     terms = {
-        "compute_s": hlo.flops / HW["peak_flops"],
-        "memory_s": hlo.hbm_bytes / HW["hbm_bw"],
-        "collective_s": hlo.collective_bytes / HW["ici_bw"],
+        "compute_s": hlo.flops / hw["peak_flops"],
+        "memory_s": hlo.hbm_bytes / hw["hbm_bw"],
+        "collective_s": hlo.collective_bytes / hw["ici_bw"],
     }
-    useful_s = model_flops / n_chips / HW["peak_flops"]
+    useful_s = model_flops / n_chips / hw["peak_flops"]
     out = {
         "arch": arch, "shape": shape_name, "mesh": mesh_name, "variant": variant,
         "compile_s": round(time.time() - t0, 1),

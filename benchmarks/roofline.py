@@ -2,8 +2,9 @@
 tables (one row per arch x shape x mesh) and rank hillclimb candidates.
 
 EXPERIMENTS.md is generated (``python -m benchmarks.make_report``); the
-hardware constants below and the collective schedules they price are
-documented in docs/ARCHITECTURE.md."""
+per-chip peaks behind the roofline terms are ``repro.launch.dryrun.PEAKS``,
+and the collective schedules they price are documented in
+docs/ARCHITECTURE.md."""
 
 from __future__ import annotations
 
@@ -12,8 +13,6 @@ import json
 import os
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results", "dryrun")
-
-HW = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9}
 
 
 def load_cells(mesh: str | None = None) -> list[dict]:

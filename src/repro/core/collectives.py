@@ -40,12 +40,6 @@ __all__ = [
 ]
 
 
-def _axis_size(name: str) -> int:
-    from ..launch.jax_compat import axis_size
-
-    return axis_size(name)
-
-
 def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Symmetric per-tensor int8 quantisation.  Returns (q, scale)."""
     amax = jnp.max(jnp.abs(x)).astype(jnp.float32)
@@ -106,9 +100,9 @@ def hierarchical_all_reduce(
     """
     denom = 1.0
     for ax in low_axes:
-        denom *= _axis_size(ax)
+        denom *= jax.lax.axis_size(ax)
     if high_axis is not None:
-        denom *= _axis_size(high_axis)
+        denom *= jax.lax.axis_size(high_axis)
     if residuals is None:
         residuals = jax.tree.map(lambda _: None, tree, is_leaf=lambda x: x is None)
 
@@ -117,7 +111,7 @@ def hierarchical_all_reduce(
         flat = g.reshape(-1).astype(jnp.float32)
         chunk = flat
         for ax in low_axes:
-            size = _axis_size(ax)
+            size = jax.lax.axis_size(ax)
             if chunk.shape[0] % size:
                 pad = size - chunk.shape[0] % size
                 chunk = jnp.concatenate([chunk, jnp.zeros((pad,), chunk.dtype)])
@@ -165,7 +159,7 @@ def two_stage_all_to_all(
     exactly matching a flat ``all_to_all`` over a ("high","low") product
     axis — verified in tests.
     """
-    nl, nh = _axis_size(low_axis), _axis_size(high_axis)
+    nl, nh = jax.lax.axis_size(low_axis), jax.lax.axis_size(high_axis)
     assert x.shape[split_axis] % (nl * nh) == 0
     # stage 1: within the clique, regroup so each low-rank holds the traffic
     # of its gateway slot for every destination high-shard

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -96,11 +98,9 @@ def flash_attention_bhsd(
     window: int = 0,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     import jax.experimental.pallas.tpu as pltpu
-
-    from ...launch.jax_compat import tpu_compiler_params
 
     bh, s, d = q.shape
     skv = k.shape[1]
@@ -129,8 +129,8 @@ def flash_attention_bhsd(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -60,6 +60,6 @@ def flash_attention(
     window: int = 0,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     return _flash_attention(q, k, v, causal, window, block_q, block_k, interpret)

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr, *, nd: int):
     di = pl.program_id(3)
@@ -46,11 +48,9 @@ def grouped_matmul(
     block_c: int = 128,
     block_d: int = 512,
     block_f: int = 128,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     import jax.experimental.pallas.tpu as pltpu
-
-    from ...launch.jax_compat import tpu_compiler_params
 
     e, c, d = x.shape
     f = w.shape[2]
@@ -71,8 +71,8 @@ def grouped_matmul(
         out_specs=pl.BlockSpec((1, block_c, block_f), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)

@@ -35,11 +35,11 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def gmm(x, w, interpret: bool = False):
+def gmm(x, w, interpret: bool | None = None):
     return _gmm(x, w, interpret)
 
 
-def expert_ffn(params, buckets, interpret: bool = False):
+def expert_ffn(params, buckets, interpret: bool | None = None):
     """SwiGLU per expert over capacity buckets — Pallas grouped GEMMs."""
     compute = buckets.dtype
     wg = params["w_gate"].astype(compute)

@@ -32,5 +32,5 @@ _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, dt, a, b, c, *, chunk: int = 256, interpret: bool = False):
+def ssd(x, dt, a, b, c, *, chunk: int = 256, interpret: bool | None = None):
     return _ssd(x, dt, a, b, c, chunk, interpret)

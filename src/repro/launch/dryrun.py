@@ -1,13 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    # dry-run only needs the post-SPMD HLO, not fast host code: keep LLVM
-    # cheap so 80 cells compile in reasonable wall time
-    "--xla_llvm_disable_expensive_passes=true "
-    "--xla_backend_optimization_level=0"
-)
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell: build the production mesh (16x16 single-pod / 2x16x16
@@ -29,6 +19,7 @@ Usage:
 
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -43,14 +34,31 @@ from ..models import build_model
 from ..optim.adamw import AdamWConfig, adamw_init
 from ..runtime import sharding as shd
 from ..runtime.trainer import make_train_step
-from .jax_compat import cost_analysis_dict, use_mesh
+from .jax_compat import use_mesh
 from .mesh import make_production_mesh
 from .specs import abstract_caches, abstract_params, cell_is_applicable, input_specs
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "benchmarks",
                            "results", "dryrun")
 
-HW = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9, "hbm_per_chip": 16e9}
+# Published per-chip peaks, keyed by ``device_kind`` as JAX reports it.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of
+# HBM at 819 GB/s, 1,600 Gbit/s of interchip interconnect over four links
+# (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9,
+                    "hbm_per_chip": 16e9},
+}
+# the chip the virtual production meshes stand for
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; an unknown chip is an error."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device_kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 def _model_flops(cfg, shape) -> float:
@@ -87,6 +95,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force: bool = False) -
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.devices.size
+    hw = peaks(DRYRUN_DEVICE_KIND)
     if shape.kind != "train":
         # serving runs bf16 weights (deployment standard); training keeps
         # fp32 masters with ZeRO/FSDP sharding of params + optimizer state.
@@ -154,7 +163,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force: bool = False) -
             t_compile = time.time() - t0 - t_lower
 
             mem = compiled.memory_analysis()
-            cost = cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
             hlo = analyze_hlo(compiled.as_text(), pod_size=256)
 
         per_device_bytes = (
@@ -164,12 +173,12 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force: bool = False) -
         model_flops = _model_flops(cfg, shape)
         hlo_flops = hlo.flops
         terms = {
-            "compute_s": hlo_flops / HW["peak_flops"],
-            "memory_s": hlo.hbm_bytes / HW["hbm_bw"],
-            "collective_s": hlo.collective_bytes / HW["ici_bw"],
+            "compute_s": hlo_flops / hw["peak_flops"],
+            "memory_s": hlo.hbm_bytes / hw["hbm_bw"],
+            "collective_s": hlo.collective_bytes / hw["ici_bw"],
         }
         dominant = max(terms, key=terms.get)
-        useful_s = model_flops / n_chips / HW["peak_flops"]
+        useful_s = model_flops / n_chips / hw["peak_flops"]
         if shape.kind == "decode":
             # decode is legitimately memory-bound: "useful" work = streaming
             # each active parameter byte + each cache byte exactly once
@@ -178,7 +187,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force: bool = False) -
                 * cfg.active_params() / max(cfg.total_params(), 1)
                 + sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches_abs))
             ) / n_chips
-            useful_s = ideal_bytes / HW["hbm_bw"]
+            useful_s = ideal_bytes / hw["hbm_bw"]
         record.update(
             status="ok",
             n_chips=n_chips,
@@ -195,7 +204,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force: bool = False) -
                 # buffers that do not exist on TPU (native bf16)
                 "state_bytes": mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes,
-                "fits_16GB": bool(per_device_bytes < HW["hbm_per_chip"]),
+                "fits_16GB": bool(per_device_bytes < hw["hbm_per_chip"]),
             },
             xla_cost={
                 "flops": cost.get("flops", -1.0),
@@ -231,6 +240,13 @@ def _write(path: str, record: dict) -> None:
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        # dry-run only needs the post-SPMD HLO, not fast host code: keep LLVM
+        # cheap so 80 cells compile in reasonable wall time
+        "--xla_llvm_disable_expensive_passes=true "
+        "--xla_backend_optimization_level=0"
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

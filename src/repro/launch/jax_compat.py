@@ -1,31 +1,25 @@
-"""Version-portable mesh/sharding layer — the repo's single pinned-JAX seam.
+"""The repo's single JAX seam: mesh construction, activation and
+manual-region entry, written for the installed JAX 0.9.
 
-The environment pins JAX 0.4.37 while the sharding APIs the codebase was
-written against (``jax.sharding.AxisType``, ``jax.sharding.get_abstract_mesh``,
-``jax.set_mesh``, top-level ``jax.shard_map``) only exist on JAX >= 0.5.
-Every version-sensitive construct lives here so future API drift fails in
-exactly one module (guarded by tests/test_jax_compat.py):
-
-* ``make_mesh``          — ``jax.make_mesh`` with the explicit ``axis_types``
-                           argument on new JAX, without it on 0.4.x.
-* ``shard_map``          — top-level ``jax.shard_map`` (``axis_names`` /
-                           ``check_vma``) vs ``jax.experimental.shard_map``
-                           (``check_rep`` / ``auto``).  On 0.4.x the region is
-                           always *full manual* over every mesh axis: partial
-                           auto with partitioned in_specs miscompiles there
-                           (XLA spmd_partitioner ``IsManualSubgroup`` abort).
+* ``make_mesh``          — ``jax.make_mesh`` with every axis ``AxisType.Auto``
+                           (0.9 defaults to ``Explicit``), so GSPMD sees the
+                           CLEX hierarchy and models place data with
+                           ``MeshContext.constrain``.
+* ``shard_map``          — ``jax.shard_map`` under ``jax.jit``.  ``axis_names``
+                           may name a subset of the mesh axes (partial manual:
+                           the rest stay auto inside the region).  The jit is
+                           what makes a partial-manual region callable outside
+                           a trace: called eagerly, 0.9 rejects its out_specs.
 * ``MeshContext``        — explicit mesh handle threaded through model and
                            runtime call signatures, replacing the implicit
                            ``jax.sharding.get_abstract_mesh()`` pattern.
 * ``use_mesh``/``active_mesh`` — repo-owned ambient mesh for launcher-level
                            code (dry-run, training loop, tests) that lowers
                            many entry points under one mesh.
-* ``cost_analysis_dict`` — ``Compiled.cost_analysis()`` returns a list of
-                           dicts on 0.4.x, a dict on newer JAX.
 
-Collective code never needs this module: ``jax.lax`` collectives are stable
-across the supported range.  Only mesh *construction*, *activation* and
-*manual-region entry* go through here.
+Collective code never needs this module: ``jax.lax`` collectives and
+``jax.lax.axis_size`` are called directly.  ``tests/test_jax_compat.py``
+pins this module against the installed JAX.
 """
 
 from __future__ import annotations
@@ -38,32 +32,14 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = [
-    "JAX_VERSION",
-    "HAS_AXIS_TYPES",
-    "HAS_TOP_LEVEL_SHARD_MAP",
     "MeshContext",
     "NO_MESH",
-    "axis_size",
     "make_mesh",
     "shard_map",
     "use_mesh",
     "active_mesh",
     "resolve_mesh",
-    "cost_analysis_dict",
 ]
-
-
-def _version_tuple(v: str) -> tuple[int, ...]:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple[int, ...] = _version_tuple(jax.__version__)
-HAS_AXIS_TYPES: bool = hasattr(jax.sharding, "AxisType")
-HAS_TOP_LEVEL_SHARD_MAP: bool = hasattr(jax, "shard_map")
 
 
 # --------------------------------------------------------------------------
@@ -72,16 +48,12 @@ HAS_TOP_LEVEL_SHARD_MAP: bool = hasattr(jax, "shard_map")
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
-    """CLEX hierarchy mesh with auto (GSPMD-visible) axis semantics on every
-    JAX in the supported range."""
-    axis_shapes = tuple(axis_shapes)
+    """CLEX hierarchy mesh with auto (GSPMD-visible) axis semantics."""
     axis_names = tuple(axis_names)
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPES:
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes), axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
 # --------------------------------------------------------------------------
@@ -133,7 +105,7 @@ class MeshContext:
 
     def constrain(self, x: jax.Array, spec: PartitionSpec) -> jax.Array:
         """``with_sharding_constraint`` bound to this mesh — works with or
-        without any ambient mesh context on every supported JAX."""
+        without any ambient mesh context."""
         return jax.lax.with_sharding_constraint(x, self.sharding(spec))
 
 
@@ -169,26 +141,15 @@ def active_mesh() -> MeshContext | None:
 @contextlib.contextmanager
 def use_mesh(mesh):
     """Activate ``mesh`` (Mesh, MeshContext, or None for a no-op) for model
-    code that was not handed an explicit mesh, and enter the native JAX mesh
-    context so spec-based APIs work on both families:
-
-    * new JAX: ``jax.sharding.use_mesh`` / ``jax.set_mesh`` (abstract mesh);
-    * 0.4.x:   the legacy ``Mesh`` context manager (resource env).
-    """
+    code that was not handed an explicit mesh, and enter JAX's own mesh
+    context (``jax.set_mesh``) so spec-based APIs see it too."""
     ctx = MeshContext.from_any(mesh)
     if ctx is None:
         yield None
         return
-    native = None
-    if hasattr(jax.sharding, "use_mesh"):
-        native = jax.sharding.use_mesh(ctx.mesh)
-    elif hasattr(jax, "set_mesh"):
-        native = jax.set_mesh(ctx.mesh)
-    else:
-        native = ctx.mesh  # legacy Mesh context manager
     _stack().append(ctx)
     try:
-        with native:
+        with jax.set_mesh(ctx.mesh):
             yield ctx
     finally:
         _stack().pop()
@@ -221,19 +182,11 @@ def _suppress_ambient():
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check: bool = False):
-    """Portable ``shard_map``.
+    """``jax.shard_map`` over ``mesh`` (Mesh or MeshContext), jitted.
 
-    ``axis_names`` is the set of manually-mapped axes (new-JAX semantics).
-    On 0.4.x the body always runs full-manual over every mesh axis, because
-    the partial-auto path (``auto=``) hard-crashes XLA 0.4.x with partitioned
-    in_specs.  Semantics are preserved (the body only names its own axes),
-    but axes outside ``axis_names`` lose GSPMD partitioning inside the
-    region: inputs whose spec does not mention such an axis are gathered and
-    their compute replicated across it.  Callers whose in_specs replicate
-    model-sharded operands (e.g. the hierarchical trainer with model > 1)
-    pay that gather on 0.4.x — acceptable for the pinned CPU test meshes,
-    a real cost on TP hardware; prefer axis-complete specs there.  The body
-    is traced with the repo-ambient mesh suppressed: inside a manual region,
+    ``axis_names`` is the set of manually-mapped axes (default: all of
+    them); the others stay under GSPMD inside the region.  The body is
+    traced with the repo-ambient mesh suppressed: inside a manual region,
     models must not emit auto sharding constraints.
     """
     ctx = MeshContext.from_any(mesh)
@@ -244,46 +197,7 @@ def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check: bool = Fa
         with _suppress_ambient():
             return f(*args)
 
-    if HAS_TOP_LEVEL_SHARD_MAP:
-        kwargs = dict(mesh=ctx.mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(body, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        body, mesh=ctx.mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
-def axis_size(name) -> int:
-    """Static size of a named mesh axis inside a manual region.
-    ``jax.lax.axis_size`` is absent on 0.4.x; psum of a unit constant folds
-    to the same static value there."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-# --------------------------------------------------------------------------
-# compile-result introspection
-# --------------------------------------------------------------------------
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every supported JAX
-    (0.4.x returns a singleton list of dicts, newer JAX the dict itself)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params across the rename:
-    ``pltpu.TPUCompilerParams`` (0.4.x) -> ``pltpu.CompilerParams`` (new)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    kwargs = dict(mesh=ctx.mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.jit(jax.shard_map(body, **kwargs))

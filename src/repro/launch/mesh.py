@@ -5,9 +5,8 @@ innermost (fastest, level-1) axis, ``data`` the intra-pod DP axis, ``pod``
 the scarce top level.  ``make_production_mesh`` builds the assignment's
 16x16 single-pod (256 chips) and 2x16x16 multi-pod (512 chips) meshes.
 
-All mesh construction goes through ``jax_compat.make_mesh`` so the same
-code runs on the pinned JAX 0.4.x (no ``axis_types``) and on >= 0.5
-(explicit ``AxisType.Auto``).
+All mesh construction goes through ``jax_compat.make_mesh``, which gives
+every axis ``AxisType.Auto`` (JAX 0.9 defaults to ``Explicit``).
 
 Functions, not module-level constants: importing this module never touches
 jax device state.

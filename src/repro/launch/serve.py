@@ -50,6 +50,7 @@ from ..runtime.orchestrator import load_schedule
 from ..runtime.serving import ContinuousBatchingEngine, ServingEngine
 from ..runtime.serving_elastic import ServingOrchestrator
 from ..runtime.sharding import reshard_params
+from .compile_cache import enable_compile_cache
 from .mesh import make_elastic_mesh, parse_mesh_flag
 from .train import finish_obs
 
@@ -106,6 +107,7 @@ def main() -> None:
                     help="dump the metrics registry and cost-model "
                          "calibration summary after the run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # --trace/--metrics install an enabled observability bundle process-wide
     # before the engine is constructed; default stays NULL_OBS

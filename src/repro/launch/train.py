@@ -41,6 +41,7 @@ from ..runtime.fault_tolerance import StragglerMonitor
 from ..runtime.orchestrator import Orchestrator, OrchestratorConfig, load_schedule
 from ..runtime.trainer import Trainer
 from .jax_compat import use_mesh
+from .compile_cache import enable_compile_cache
 from .mesh import make_elastic_mesh, parse_mesh_flag
 
 
@@ -96,6 +97,7 @@ def main() -> None:
                     help="dump the metrics registry and cost-model "
                          "calibration summary after the run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # --trace/--metrics install an enabled observability bundle process-wide
     # before any orchestrator/engine is constructed; default stays NULL_OBS

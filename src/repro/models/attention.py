@@ -195,7 +195,7 @@ def attention_apply(
         if impl == "pallas":
             from ..kernels.flash_attention import ops as fa_ops
 
-            out = fa_ops.flash_attention(q, k, v, causal=True, window=window, interpret=True)
+            out = fa_ops.flash_attention(q, k, v, causal=True, window=window)
         else:
             out = blockwise_attention(
                 q, k, v, causal=True, window=window, q_offset=0, scale=scale

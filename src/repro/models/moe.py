@@ -116,7 +116,7 @@ def moe_local(params, x_flat, cfg: ModelConfig, *, impl: str = "xla"):
     if impl == "pallas":
         from ..kernels.moe_gmm import ops as gmm_ops
 
-        out_buckets = gmm_ops.expert_ffn(params, buckets, interpret=True)
+        out_buckets = gmm_ops.expert_ffn(params, buckets)
     else:
         out_buckets = _expert_ffn(params, buckets, compute)
     gathered = out_buckets[flat_e, jnp.where(keep, slot, 0)]  # [T*k, D]
@@ -181,7 +181,7 @@ def moe_replicated_ep(params, x_flat, cfg: ModelConfig, *, model_axis: str = "mo
     moe = cfg.moe
     compute = x_flat.dtype
     t = x_flat.shape[0]
-    m = jax_compat.axis_size(model_axis)
+    m = jax.lax.axis_size(model_axis)
     rank = jax.lax.axis_index(model_axis)
     e_local = moe.n_experts // m
 

@@ -229,7 +229,7 @@ def ssm_apply(
         if impl == "pallas":
             from ..kernels.ssd_scan import ops as ssd_ops
 
-            y, h_final = ssd_ops.ssd(xc, dtp, a, bc, ccg, chunk=c.chunk_size, interpret=True)
+            y, h_final = ssd_ops.ssd(xc, dtp, a, bc, ccg, chunk=c.chunk_size)
         else:
             y, h_final = ssd_chunked(xc, dtp, a, bc, ccg, chunk=min(c.chunk_size, s))
         new_cache = None

@@ -13,7 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from benchmarks.hlo_analysis import analyze_hlo
 from repro.configs.base import ParallelConfig, ShapeConfig, get_config
-from repro.launch.jax_compat import cost_analysis_dict, make_mesh, use_mesh
+from repro.launch.jax_compat import make_mesh, use_mesh
 from repro.launch.specs import abstract_caches, abstract_params, input_specs
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig, adamw_init
@@ -94,4 +94,26 @@ def test_prefill_cell_lowers_and_compiles(mesh):
         compiled = jax.jit(model.prefill, in_shardings=(params_sh, batch_sh)).lower(
             params_abs, batch
         ).compile()
-    assert cost_analysis_dict(compiled)["flops"] > 0
+    assert compiled.cost_analysis()["flops"] > 0
+
+
+def test_importing_dryrun_leaves_xla_flags_alone(monkeypatch):
+    """The 512-device host setting belongs to the dry-run CLI's ``main``:
+    importing the module (as the perf tools do) must not change it."""
+    import importlib
+    import os
+    import sys
+
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    monkeypatch.delitem(sys.modules, "repro.launch.dryrun", raising=False)
+    importlib.import_module("repro.launch.dryrun")
+    assert os.environ["XLA_FLAGS"] == "--xla_force_host_platform_device_count=8"
+
+
+def test_peak_table_is_keyed_by_device_kind():
+    from repro.launch.dryrun import DRYRUN_DEVICE_KIND, peaks
+
+    v5e = peaks(DRYRUN_DEVICE_KIND)
+    assert v5e["peak_flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")

@@ -10,7 +10,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.hlo_analysis import _expand_replica_groups, analyze_hlo
-from repro.launch.jax_compat import cost_analysis_dict, make_mesh, use_mesh
+from repro.launch.jax_compat import make_mesh, use_mesh
 
 
 def test_plain_matmul_flops_exact():
@@ -35,14 +35,14 @@ def test_scan_trip_count_scaling():
     a = analyze_hlo(c.as_text())
     # XLA's own cost_analysis undercounts by 4x; ours must not
     assert a.flops == pytest.approx(4 * 2 * 64**3, rel=0.01)
-    assert cost_analysis_dict(c)["flops"] < a.flops / 2
+    assert c.cost_analysis()["flops"] < a.flops / 2
 
 
 def test_spmd_per_device_flops_and_collectives():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
     mesh = make_mesh((8,), ("model",))
-    ns = lambda spec: NamedSharding(mesh, spec)  # 0.4.x jit rejects raw specs
+    ns = lambda spec: NamedSharding(mesh, spec)  # bound to the mesh, not the ambient one
     with use_mesh(mesh):
         c = jax.jit(
             lambda a, b: a @ b,
@@ -63,7 +63,7 @@ def test_collective_inside_scan_counts_trips():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
     mesh = make_mesh((8,), ("model",))
-    ns = lambda spec: NamedSharding(mesh, spec)  # 0.4.x jit rejects raw specs
+    ns = lambda spec: NamedSharding(mesh, spec)  # bound to the mesh, not the ambient one
 
     def f(x):
         def body(h, _):

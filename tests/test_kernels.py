@@ -248,3 +248,28 @@ def test_ssd_chunk_invariance(chunks, h, p, n, seed):
     y2, h2 = reference_ssd(x, dt, a, bb, cc)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------- call sites
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "mamba2-1.3b", "olmoe-1b-7b"])
+def test_model_call_site_interprets_on_cpu(arch):
+    """The model's ``impl="pallas"`` call sites pass no ``interpret``: the
+    kernels' one switch picks the interpreter off the TPU (compiling a
+    Pallas TPU kernel for the CPU backend would raise), and the result
+    matches the XLA path."""
+    import dataclasses
+
+    from repro.configs.base import get_config
+    from repro.kernels import resolve_interpret
+    from repro.models import build_model
+
+    assert jax.default_backend() != "tpu"
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    cfg = dataclasses.replace(get_config(arch, reduced=True), compute_dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    tokens = jnp.asarray(np.random.default_rng(9).integers(1, cfg.vocab, (2, 32)), jnp.int32)
+    ref, _ = model.prefill(params, {"tokens": tokens}, impl="xla")
+    out, _ = model.prefill(params, {"tokens": tokens}, impl="pallas")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-4, rtol=2e-4)

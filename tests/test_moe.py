@@ -105,3 +105,21 @@ def test_valiant_shuffle_preserves_semantics(mesh):
             lambda p, x, k: moe_apply(p, x, cfg_v, key=k)
         )(params, x, jax.random.PRNGKey(7))
     np.testing.assert_allclose(np.asarray(out_val), np.asarray(out_plain), atol=2e-5)
+
+
+@pytest.mark.parametrize("jit", [True, False])
+def test_sharded_a2a_matches_local_on_4_devices(jit):
+    """The token-sharded a2a path through ``jax_compat.shard_map`` on a
+    2x2 (data x model) mesh, traced under an outer jit and called eagerly:
+    both must equal the local oracle."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+    cfg = _cfg()
+    params = _params(cfg, jax.random.PRNGKey(0))
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(2, T // 2, D)), jnp.float32)
+    ref, _ = moe_local(params, x.reshape(T, D), cfg)
+    fn = lambda p, x: moe_apply(p, x, cfg, mesh=mesh)
+    out, aux = (jax.jit(fn) if jit else fn)(params, x)
+    np.testing.assert_allclose(np.asarray(out.reshape(T, D)), np.asarray(ref), atol=2e-5)
+    assert np.isfinite(float(aux))

@@ -363,6 +363,7 @@ def test_train_launcher_trace_smoke(tmp_path, monkeypatch):
     Perfetto-loadable trace containing remesh spans."""
     from repro.launch import train as train_mod
 
+    monkeypatch.setattr(train_mod, "enable_compile_cache", lambda: None)
     trace = tmp_path / "train_trace.json"
     monkeypatch.setattr(sys, "argv", [
         "train", "--reduced", "--orchestrate", "--steps", "3", "--batch", "4",
@@ -381,6 +382,7 @@ def test_serve_launcher_trace_smoke(tmp_path, monkeypatch):
     Perfetto-loadable trace containing migrate spans."""
     from repro.launch import serve as serve_mod
 
+    monkeypatch.setattr(serve_mod, "enable_compile_cache", lambda: None)
     trace = tmp_path / "serve_trace.json"
     monkeypatch.setattr(sys, "argv", [
         "serve", "--reduced", "--orchestrate", "--requests", "4", "--slots",
