@@ -374,6 +374,25 @@ class _StepClock:
         return self.t
 
 
+def _virtual_done(engine, rids) -> dict:
+    """``{rid: the virtual now of the step that finished it}``, filled in as
+    ``engine`` steps.  Request stamps add the host seconds of the step to
+    its ``now`` (docs/OBSERVABILITY.md), which a virtual latency must not
+    see."""
+    done_at, pending = {}, set(rids)
+    step = engine.step
+
+    def stepping(now=None):
+        made = step(now)
+        for rid in [r for r in pending if engine.requests[r].done]:
+            done_at[rid] = now
+            pending.discard(rid)
+        return made
+
+    engine.step = stepping
+    return done_at
+
+
 def _run_diurnal_path(model, params, prompts, budgets, arrivals, slots,
                       max_len, spec, *, closed_loop, shed_depth, gain_step,
                       window):
@@ -412,12 +431,10 @@ def _run_diurnal_path(model, params, prompts, budgets, arrivals, slots,
         engine.submit(p, b, arrival_time=float(t))
         for p, b, t in zip(prompts, budgets, arrivals)
     ]
+    done_at = _virtual_done(engine, rids)
     out = orch.run(clock=_StepClock())
     rep = orch.report
-    lat = [
-        engine.requests[r].t_done - engine.requests[r].arrival_time
-        for r in rids if r in out
-    ]
+    lat = [done_at[r] - engine.requests[r].arrival_time for r in rids if r in out]
     # Fixed window right after the gain boundary, where both paths are
     # still backlog-saturated.  Averaging to end-of-run instead would
     # dilute the closed loop with its (faster) drain-down tail and hide

@@ -28,7 +28,7 @@ from .calibration import CalibrationLedger, CalibrationRecord, summarize_records
 from .logging import log
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .provenance import SUITE_VERSION, provenance
-from .trace import NULL_SPAN, Span, Tracer, load_chrome, load_jsonl
+from .trace import NULL_SPAN, Span, Tracer, lane, load_chrome, load_jsonl
 
 __all__ = [
     "CalibrationLedger",
@@ -44,6 +44,7 @@ __all__ = [
     "Span",
     "Tracer",
     "get_obs",
+    "lane",
     "load_chrome",
     "load_jsonl",
     "log",

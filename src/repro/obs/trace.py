@@ -14,11 +14,20 @@ resulting event list exports two ways:
   per category) loadable in ``ui.perfetto.dev`` / ``chrome://tracing``
   (:func:`load_chrome` re-parses it back to event dicts).
 
-Zero-cost discipline: the disabled path never reaches this module — the
-:class:`~repro.obs.Obs` bundle returns the preallocated :data:`NULL_SPAN`
-singleton (whose ``__enter__``/``__exit__`` allocate nothing) without
-constructing a tracer at all.  The overhead guard in ``tests/test_obs.py``
-pins this with ``tracemalloc``.
+Every span of an enabled tracer also lands on the JAX profiler's own
+timeline: entering it enters a ``jax.profiler.TraceAnnotation`` named
+``"<cat>.<name>"`` (``serve.decode``), so a device trace shows what the host
+was doing between the device's programs.  The annotation carries the name
+only (attributes stay in the tracer's event), so the name is a stable key
+for whoever reads the profile.  :func:`lane` gives a host whose own tracing
+is off the same annotation, and only while a profiler session records.
+
+Zero-cost discipline: the disabled :class:`~repro.obs.Obs` bundle
+returns the preallocated :data:`NULL_SPAN` singleton (whose
+``__enter__``/``__exit__`` allocate nothing) without constructing a tracer
+at all, and :func:`lane`, the one hook here a disabled host calls, returns
+it too while no profiler records.  The overhead guard in
+``tests/test_obs.py`` pins this with ``tracemalloc``.
 """
 
 from __future__ import annotations
@@ -32,6 +41,7 @@ __all__ = [
     "NULL_SPAN",
     "Span",
     "Tracer",
+    "lane",
     "load_chrome",
     "load_jsonl",
 ]
@@ -55,12 +65,32 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def _profiler_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def lane(name: str):
+    """``name`` on the profiler's timeline alone, for a host whose tracer is
+    off: a ``TraceAnnotation`` while a profiler session records, else
+    :data:`NULL_SPAN` (no allocation).  ``name`` is the ``"<cat>.<name>"``
+    an enabled tracer's span would carry."""
+    annotation = _profiler_annotation()
+    return annotation(name) if annotation.is_enabled() else NULL_SPAN
+
 
 class Span:
     """A live span; append-on-exit so a crash inside the body still leaves
     the tracer consistent (the unfinished span simply never lands)."""
 
-    __slots__ = ("_tracer", "name", "cat", "step", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "step", "args", "_t0", "_lane")
 
     def __init__(self, tracer, name, cat, step, args):
         self._tracer = tracer
@@ -78,11 +108,15 @@ class Span:
         return self
 
     def __enter__(self):
+        self._lane = _profiler_annotation()(f"{self.cat}.{self.name}")
+        self._lane.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._finish(self, time.monotonic())
+        t1 = time.monotonic()
+        self._lane.__exit__(*exc)
+        self._tracer._finish(self, t1)
         return False
 
 

@@ -39,6 +39,7 @@ import bisect
 import dataclasses
 import heapq
 import itertools
+import threading
 import time
 from collections import OrderedDict
 from functools import partial
@@ -50,7 +51,7 @@ import numpy as np
 
 from ..core.collectives import CollectiveCostModel
 from ..models import Model
-from ..obs import NULL_SPAN, get_obs
+from ..obs import NULL_SPAN, get_obs, lane
 from ..obs.metrics import MetricsRegistry, registry_field
 
 __all__ = [
@@ -109,6 +110,10 @@ class Request:
     tokens_out: list = dataclasses.field(default_factory=list)
     deferred: int = 0  # admission rounds the scheduler has deferred this request
     slot: Optional[int] = None
+    # stamps on the clock of step()'s ``now``, taken when the work is done
+    # on the host: t_admit as the request's prefill starts (or its session
+    # row is paged back in), t_first / t_done once its first / last token
+    # is on the host
     t_submit: float = 0.0
     t_admit: Optional[float] = None
     t_first: Optional[float] = None
@@ -820,6 +825,9 @@ class EngineMetrics:
         ("rejected", 0),  # refused at submit (queue over max_queue_depth)
         ("deadline_drops", 0),  # dropped unadmitted past their deadline
         ("shed_tokens", 0),  # token budget of all shed requests (not served)
+        # backend compiles JAX reported during the engine's prefill/decode
+        # calls: each is a step that paid for a new program
+        ("compiles", 0),
     )
 
     def __init__(self, registry: MetricsRegistry | None = None):
@@ -837,6 +845,31 @@ class EngineMetrics:
 for _name, _default in EngineMetrics._SCALARS:
     setattr(EngineMetrics, _name, registry_field(f"serve.engine.{_name}"))
 del _name, _default
+
+
+# the engine's jitted call in flight on this thread, as (engine, program,
+# shape), so a backend compile JAX reports is put down to the step that paid
+_IN_CALL = threading.local()
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_listening = False
+
+
+def _on_jax_event(event: str, secs: float, **_) -> None:
+    call = getattr(_IN_CALL, "call", None)
+    if event != _BACKEND_COMPILE or call is None:
+        return
+    engine, program, shape = call
+    engine.metrics.compiles += 1
+    if engine._obs.enabled:
+        engine._obs.tracer.instant("compile", "serve", program=program,
+                                   shape=list(shape), secs=secs)
+
+
+def _count_compiles() -> None:
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+        _listening = True
 
 
 class ContinuousBatchingEngine:
@@ -932,6 +965,8 @@ class ContinuousBatchingEngine:
 
         self._reset_slot_state(n_slots)
         self._build_jits()
+        self._clock_offset = 0.0  # step()'s ``now`` minus time.monotonic()
+        _count_compiles()
 
     def _make_pool(self, n_slots: int, capacity: int) -> KVPool:
         if self.tiers is not None:
@@ -1240,25 +1275,39 @@ class ContinuousBatchingEngine:
                 i += g
         return groups
 
-    def _admit_group(self, group: list[Request], now: float) -> None:
-        g = len(group)
-        slots = [self.pool.allocate(r.rid) for r in group]
-        assert all(s is not None for s in slots)
-        for r in group:
-            if r.sample_rid is None:
-                r.sample_rid = r.rid
-        bucket = max(self._bucket(r.prompt_len) for r in group)
-        toks = np.full((g, bucket), self.pad_id, np.int32)
-        for i, r in enumerate(group):
-            toks[i, : r.prompt_len] = r.prompt
+    def _stamp(self) -> float:
+        """The present on the clock of the running step()'s ``now``."""
+        return time.monotonic() + self._clock_offset
+
+    def _call(self, program: str, shape: tuple, fn, *args):
+        """One jitted call, with any backend compile it pays counted."""
+        _IN_CALL.call = (self, program, shape)
+        try:
+            return fn(*args)
+        finally:
+            _IN_CALL.call = None
+
+    def _admit_group(self, group: list[Request]) -> None:
         obs = self._obs
+        g = len(group)
+        bucket = max(self._bucket(r.prompt_len) for r in group)
         span = (
             obs.tracer.span("prefill", "serve", group=g, bucket=bucket)
-            if obs.enabled else NULL_SPAN
+            if obs.enabled else lane("serve.prefill")
         )
-        t0 = time.monotonic()
         with span:
-            firsts, self.pool.caches = self._prefill_into(
+            t_admit = self._stamp()
+            slots = [self.pool.allocate(r.rid) for r in group]
+            assert all(s is not None for s in slots)
+            for r in group:
+                if r.sample_rid is None:
+                    r.sample_rid = r.rid
+            toks = np.full((g, bucket), self.pad_id, np.int32)
+            for i, r in enumerate(group):
+                toks[i, : r.prompt_len] = r.prompt
+            t0 = time.monotonic()
+            firsts, self.pool.caches = self._call(
+                "prefill_into", (g, bucket), self._prefill_into,
                 self.params,
                 jnp.asarray(toks),
                 jnp.asarray([r.prompt_len for r in group], jnp.int32),
@@ -1269,7 +1318,9 @@ class ContinuousBatchingEngine:
                 jnp.asarray([r.idx_base for r in group], jnp.int32),
             )
             self.metrics.prefills += 1
-            firsts = np.asarray(firsts)
+            with obs.tracer.span("sync", "serve") if obs.enabled else lane("serve.sync"):
+                firsts = np.asarray(firsts)
+            t_first = self._stamp()
         if obs.enabled:
             # calibration: the modeled cold-prefill price of the group vs
             # the batched prefill wall (includes the device sync above)
@@ -1288,8 +1339,8 @@ class ContinuousBatchingEngine:
             tok = int(firsts[i])
             req.state = RUNNING
             req.slot = slot
-            req.t_admit = now
-            req.t_first = now
+            req.t_admit = t_admit
+            req.t_first = t_first
             req.tokens_out.append(tok)
             req.last_token = tok
             if self.audit_enabled:
@@ -1299,9 +1350,9 @@ class ContinuousBatchingEngine:
             self._pos[slot] = req.prompt_len
             self._temps[slot] = req.temperature
             self._rids[slot] = req.sample_rid
-            self._maybe_finish(req, tok, now)
+            self._maybe_finish(req, tok, t_first)
 
-    def _admit_resume(self, req: Request, now: float) -> None:
+    def _admit_resume(self, req: Request) -> None:
         """Wake a tier-resident session: page its row into a free slot and
         resume decode where it left off — no prefill at all.  The first new
         token comes from the next decode step (t_first is stamped then)."""
@@ -1328,7 +1379,7 @@ class ContinuousBatchingEngine:
             slot, rec = self.pool.promote(req.session_id, req.rid)
         req.state = RUNNING
         req.slot = slot
-        req.t_admit = now
+        req.t_admit = self._stamp()
         req.sample_rid = rec.sample_rid
         req.idx_base = rec.idx_base
         req.last_token = rec.last_token
@@ -1339,11 +1390,12 @@ class ContinuousBatchingEngine:
         self._rids[slot] = rec.sample_rid
         self.metrics.wakeups += 1
 
-    def _maybe_finish(self, req: Request, last_tok: int, now: float) -> None:
+    def _maybe_finish(self, req: Request, last_tok: int, t_tok: float) -> None:
+        """Finish ``req`` if ``last_tok`` (on the host at ``t_tok``) ends it."""
         hit_eos = req.eos_id is not None and last_tok == req.eos_id
         if hit_eos or len(req.tokens_out) >= req.max_new_tokens:
             req.state = FINISHED
-            req.t_done = now
+            req.t_done = t_tok
             slot = req.slot
             if req.session_id is not None and self.pool.tiered:
                 # park the session in the hierarchy instead of discarding:
@@ -1405,14 +1457,80 @@ class ContinuousBatchingEngine:
 
     def step(self, now: Optional[float] = None) -> int:
         """One scheduling round: admit, then one ragged decode step for all
-        active slots.  Returns the number of tokens produced."""
+        active slots.  Returns the number of tokens produced.
+
+        ``now`` (default ``time.monotonic()``) gates arrivals and deadlines;
+        request stamps are taken on its clock as the work they mark is done
+        (``now`` plus the time since the step began)."""
+        t0 = time.monotonic()
         if now is None:
-            now = time.monotonic()
-        produced = 0
+            now = t0
+        self._clock_offset = now - t0
         obs = self._obs
         if obs.enabled:
             obs.tracer.step = self.metrics.steps
+        with obs.tracer.span("step", "serve") if obs.enabled else lane("serve.step"):
+            produced = self._step(now)
+        self.metrics.steps += 1
+        return produced
 
+    def _step(self, now: float) -> int:
+        obs = self._obs
+        produced = 0
+        with obs.tracer.span("admit", "serve") if obs.enabled else lane("serve.admit"):
+            groups = self._admit(now)
+        for group in groups:
+            self._admit_group(group)
+            produced += len(group)
+
+        # ---- one decode step over the pool
+        active = [r for r in self._slot_req if r is not None]
+        if not active:
+            return produced
+        with obs.tracer.span("decode", "serve") if obs.enabled else lane("serve.decode"):
+            idxs = np.array(
+                [
+                    r.idx_base + len(r.tokens_out) if r is not None else 0
+                    for r in self._slot_req
+                ],
+                np.int32,
+            )
+            toks, self.pool.caches = self._call(
+                "decode", (self.pool.n_slots,), self._decode,
+                self.params,
+                self.pool.caches,
+                jnp.asarray(self._tokens),
+                jnp.asarray(self._pos),
+                jnp.asarray(self._temps),
+                jnp.asarray(self._rids),
+                jnp.asarray(idxs),
+            )
+            with obs.tracer.span("sync", "serve") if obs.enabled else lane("serve.sync"):
+                toks = np.asarray(toks)
+            t_tok = self._stamp()
+        self.metrics.decode_steps += 1
+        self.metrics.total_slot_steps += self.pool.n_slots
+        with obs.tracer.span("emit", "serve") if obs.enabled else lane("serve.emit"):
+            for slot, req in enumerate(self._slot_req):
+                if req is None:
+                    continue
+                tok = int(toks[slot])
+                if self.audit_enabled:
+                    self.audit.append((req.rid, len(req.tokens_out)))
+                req.tokens_out.append(tok)
+                req.last_token = tok
+                if req.t_first is None:
+                    req.t_first = t_tok  # woken sessions skip prefill
+                self._tokens[slot] = tok
+                self._pos[slot] += 1
+                self.metrics.active_slot_steps += 1
+                produced += 1
+                self._maybe_finish(req, tok, t_tok)
+        return produced
+
+    def _admit(self, now: float) -> list[list[Request]]:
+        """Shed expired requests, pick arrived ones for the free slots, wake
+        resumed sessions; returns the prefill groups of the rest."""
         # ---- deadline drops: an unadmitted request past its deadline is
         # worthless — refund it from the queue before it wastes a slot
         expired = [
@@ -1426,88 +1544,42 @@ class ContinuousBatchingEngine:
         candidates = (
             [] if self._paused or not self.pool.n_free else self.queue.arrived(now)
         )
-        if candidates:
-            if self.pool.tiered:
-                # refresh each session request's wakeup hint — residency can
-                # change between rounds as other demotions spill the ledger
-                for r in candidates:
-                    if r.session_id is not None:
-                        rec = self.pool.lookup(r.session_id)
-                        resident = rec is not None and rec.row is not None
-                        r.resume_tier = rec.tier if resident else None
-                        r.resume_bytes = rec.nbytes if resident else 0
-            n_heavy_active = sum(
-                1 for r in self._slot_req if r is not None and r.moe_heavy
-            )
-            picks = self.scheduler.select(candidates, self.pool.n_free, n_heavy_active)
-            self.queue.remove(picks)
-            cold: list[Request] = []
-            for r in picks:
-                if (
-                    self.pool.tiered
-                    and r.session_id is not None
-                    and self.pool.session_tier(r.session_id) in ("host", "pooled")
-                ):
-                    self._admit_resume(r, now)  # wakeup: no prefill
-                    continue
-                if self.pool.tiered and r.session_id is not None:
-                    rec = self.pool.claim_dropped(r.session_id)
-                    if rec is not None:
-                        # row was dropped: re-prefill the full history but
-                        # keep the sampling identity — still bit-exact
-                        r.sample_rid = rec.sample_rid
-                        r.idx_base = rec.idx_base
-                        self.metrics.cold_resumes += 1
-                cold.append(r)
-            for group in self._admission_groups(cold):
-                self._admit_group(group, now)
-                produced += len(group)
-            self.metrics.predicted_a2a_s += self.scheduler.last_step_cost
-
-        # ---- one decode step over the pool
-        active = [r for r in self._slot_req if r is not None]
-        if active:
-            idxs = np.array(
-                [
-                    r.idx_base + len(r.tokens_out) if r is not None else 0
-                    for r in self._slot_req
-                ],
-                np.int32,
-            )
-            span = (
-                obs.tracer.span("decode", "serve") if obs.enabled else NULL_SPAN
-            )
-            with span:
-                toks, self.pool.caches = self._decode(
-                    self.params,
-                    self.pool.caches,
-                    jnp.asarray(self._tokens),
-                    jnp.asarray(self._pos),
-                    jnp.asarray(self._temps),
-                    jnp.asarray(self._rids),
-                    jnp.asarray(idxs),
-                )
-                toks = np.asarray(toks)
-            self.metrics.decode_steps += 1
-            self.metrics.total_slot_steps += self.pool.n_slots
-            for slot, req in enumerate(self._slot_req):
-                if req is None:
-                    continue
-                tok = int(toks[slot])
-                if self.audit_enabled:
-                    self.audit.append((req.rid, len(req.tokens_out)))
-                req.tokens_out.append(tok)
-                req.last_token = tok
-                if req.t_first is None:
-                    req.t_first = now  # woken sessions skip prefill
-                self._tokens[slot] = tok
-                self._pos[slot] += 1
-                self.metrics.active_slot_steps += 1
-                produced += 1
-                self._maybe_finish(req, tok, now)
-
-        self.metrics.steps += 1
-        return produced
+        if not candidates:
+            return []
+        if self.pool.tiered:
+            # refresh each session request's wakeup hint — residency can
+            # change between rounds as other demotions spill the ledger
+            for r in candidates:
+                if r.session_id is not None:
+                    rec = self.pool.lookup(r.session_id)
+                    resident = rec is not None and rec.row is not None
+                    r.resume_tier = rec.tier if resident else None
+                    r.resume_bytes = rec.nbytes if resident else 0
+        n_heavy_active = sum(
+            1 for r in self._slot_req if r is not None and r.moe_heavy
+        )
+        picks = self.scheduler.select(candidates, self.pool.n_free, n_heavy_active)
+        self.queue.remove(picks)
+        cold: list[Request] = []
+        for r in picks:
+            if (
+                self.pool.tiered
+                and r.session_id is not None
+                and self.pool.session_tier(r.session_id) in ("host", "pooled")
+            ):
+                self._admit_resume(r)  # wakeup: no prefill
+                continue
+            if self.pool.tiered and r.session_id is not None:
+                rec = self.pool.claim_dropped(r.session_id)
+                if rec is not None:
+                    # row was dropped: re-prefill the full history but
+                    # keep the sampling identity — still bit-exact
+                    r.sample_rid = rec.sample_rid
+                    r.idx_base = rec.idx_base
+                    self.metrics.cold_resumes += 1
+            cold.append(r)
+        self.metrics.predicted_a2a_s += self.scheduler.last_step_cost
+        return self._admission_groups(cold)
 
     def run(
         self,

@@ -316,6 +316,109 @@ def test_tiered_serving_records_wakeup_and_tier_transfer(model):
     assert reg["serve.pool.n_demote"].value == eng.pool.n_demote
 
 
+# ------------------------------------------------------- profiler timeline
+def _host_spans(trace_dir, prefix="serve."):
+    """``[(name, start_ns, end_ns)]`` of the host events under ``prefix`` in
+    the one ``.xplane.pb`` a profiler session wrote to ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    found = list(trace_dir.rglob("*.xplane.pb"))
+    assert len(found) == 1, found
+    profile = ProfileData.from_file(str(found[0]))
+    return sorted(
+        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+        for plane in profile.planes if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events if e.name.startswith(prefix)
+    )
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def _serve_under_profiler(model, trace_dir, obs):
+    """Two requests through a tiny engine while a profiler session records;
+    returns the engine and the steps it took."""
+    from repro.runtime.serving import ContinuousBatchingEngine
+
+    params = model.init(jax.random.PRNGKey(2))
+    eng = ContinuousBatchingEngine(model, params, n_slots=2, max_len=32, seed=0,
+                                   policy="fcfs", obs=obs)
+    rng = np.random.default_rng(4)
+    for n in (5, 7):
+        eng.submit(rng.integers(1, model.cfg.vocab, (n,)).astype(np.int32), 6)
+    eng.step()  # compiles outside the recorded stretch
+    steps0 = eng.metrics.steps
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    return eng, eng.metrics.steps - steps0
+
+
+def test_engine_spans_land_on_the_profiler_timeline(model, tmp_path):
+    """An enabled tracer's spans are ``TraceAnnotation``s named
+    ``<cat>.<name>``: the profile's host plane holds one ``serve.step`` per
+    step taken, each decode nested in its step and each sync in its decode,
+    while the tracer keeps its own events (attributes included)."""
+    ob = Obs()
+    eng, steps = _serve_under_profiler(model, tmp_path, ob)
+    spans = _host_spans(tmp_path)
+    by_name = {}
+    for ev in spans:
+        by_name.setdefault(ev[0], []).append(ev)
+    assert steps >= 2
+    assert len(by_name["serve.step"]) == steps
+    assert len(by_name["serve.decode"]) == steps
+    assert len(by_name["serve.emit"]) == steps
+    for dec in by_name["serve.decode"]:
+        assert sum(_inside(dec, st) for st in by_name["serve.step"]) == 1
+        assert sum(_inside(sy, dec) for sy in by_name["serve.sync"]) == 1
+    assert all(any(_inside(a, st) for st in by_name["serve.step"])
+               for a in by_name["serve.admit"])
+    # names carry no attributes: the tracer's own events do
+    assert {e["name"] for e in ob.tracer.events} >= {"step", "admit", "decode", "sync", "emit"}
+    assert all(e["args"]["bucket"] == 8 for e in ob.tracer.events if e["name"] == "prefill")
+
+
+def test_a_disabled_engine_shows_on_the_timeline_only_while_profiled(model, tmp_path):
+    """With the tracer off the engine builds nothing for its spans, except
+    the profiler's annotations while a profiler session records."""
+    from repro.obs import lane
+
+    assert lane("serve.step") is NULL_SPAN  # no session recording
+    eng, steps = _serve_under_profiler(model, tmp_path, NULL_OBS)
+    names = [ev[0] for ev in _host_spans(tmp_path)]
+    assert names.count("serve.step") == steps
+    assert names.count("serve.sync") >= steps
+    assert lane("serve.step") is NULL_SPAN
+
+
+def test_engine_counts_backend_compiles_at_the_step_that_paid(model):
+    """A new program shape compiles inside the engine's call: the engine
+    counts it and the tracer marks it (program and shape); the same shapes
+    again compile nothing."""
+    from repro.runtime.serving import ContinuousBatchingEngine
+
+    ob = Obs()
+    params = model.init(jax.random.PRNGKey(3))
+    # a pool size no other test builds: its programs are new to this process
+    eng = ContinuousBatchingEngine(model, params, n_slots=3, max_len=24, seed=7,
+                                   policy="fcfs", obs=ob)
+    eng.submit(np.arange(1, 6, dtype=np.int32), 2)
+    eng.run()
+    marks = [e for e in ob.tracer.events if e["name"] == "compile"]
+    assert eng.metrics.compiles == len(marks) >= 2
+    assert {m["args"]["program"] for m in marks} == {"prefill_into", "decode"}
+    assert {tuple(m["args"]["shape"]) for m in marks} == {(1, 8), (3,)}
+    assert {m["step"] for m in marks} == {0}
+    assert ob.registry["serve.engine.compiles"].value == eng.metrics.compiles
+    eng.submit(np.arange(1, 6, dtype=np.int32), 2)
+    eng.run()
+    assert eng.metrics.compiles == len(marks)
+
+
 # ---------------------------------------------------------- overhead guard
 def test_disabled_path_allocates_no_trace_objects():
     """The zero-cost-when-disabled contract: driving every hot-path hook

@@ -3,6 +3,7 @@ policies, sampling determinism, and equivalence against the one-shot path.
 (docs/SERVING.md documents the behaviours pinned here.)"""
 
 import dataclasses
+import time
 
 import jax
 import numpy as np
@@ -187,6 +188,63 @@ def test_ragged_admission_and_slot_reuse(tiny):
     # FIFO: earlier submissions are admitted no later than later ones
     admits = [eng.requests[r].t_admit for r in rids]
     assert all(a <= b for a, b in zip(admits, admits[1:])) or sorted(admits) == admits
+
+
+def _slow_decode(eng, secs):
+    decode = eng._decode
+
+    def slow(*args):
+        time.sleep(secs)
+        return decode(*args)
+
+    eng._decode = slow
+
+
+def test_request_stamps_mark_when_the_work_is_done(tiny):
+    """Stamps are taken as the host gets the work back, not at the start of
+    the step: a request served by decode spans at least the decode's time
+    between admission and its last token."""
+    model, params = tiny
+    eng = ContinuousBatchingEngine(model, params, n_slots=2, max_len=48,
+                                   policy="fcfs", seed=0)
+    rng = np.random.default_rng(11)
+    rids = [eng.submit(p, b) for p, b in
+            zip(_prompts(rng, model.cfg.vocab, [5, 9, 3]), [1, 3, 2])]
+    eng.run()  # compiles
+    _slow_decode(eng, 0.02)
+    rids = [eng.submit(p, b) for p, b in
+            zip(_prompts(rng, model.cfg.vocab, [5, 9, 3]), [1, 3, 2])]
+    t0 = time.monotonic()
+    eng.run()
+    t1 = time.monotonic()
+    for rid in rids:
+        r = eng.requests[rid]
+        assert t0 <= r.t_admit <= r.t_first <= r.t_done <= t1
+    one, three, two = (eng.requests[r] for r in rids)
+    assert one.t_done == one.t_first  # finished by its prefill
+    for r in (three, two):
+        assert r.t_done - r.t_admit >= 0.02 * (len(r.tokens_out) - 1)
+
+
+def test_request_stamps_stay_on_the_callers_clock(tiny):
+    """A simulated ``now`` puts the stamps on its clock: offset from that
+    ``now`` by the host time the step took, not read off the wall clock."""
+    model, params = tiny
+    eng = ContinuousBatchingEngine(model, params, n_slots=2, max_len=48,
+                                   policy="fcfs", seed=0)
+    rng = np.random.default_rng(12)
+    warm = eng.submit(_prompts(rng, model.cfg.vocab, [4])[0], 2)
+    eng.run()  # compiles
+    assert eng.requests[warm].done
+    _slow_decode(eng, 0.02)
+    rid = eng.submit(_prompts(rng, model.cfg.vocab, [4])[0], 2, arrival_time=1000.0)
+    assert eng.step(999.0) == 0  # not arrived on the caller's clock
+    t0 = time.monotonic()
+    assert eng.step(1000.0) == 2
+    took = time.monotonic() - t0
+    r = eng.requests[rid]
+    assert 1000.0 <= r.t_admit <= r.t_first <= r.t_done <= 1000.0 + took
+    assert r.t_done - r.t_first >= 0.02
 
 
 def test_submit_rejects_over_capacity(tiny):
