@@ -72,8 +72,7 @@ def run_variant(arch: str, shape_name: str, mesh_name: str, variant: str) -> dic
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     if shape.kind != "train":
-        cfg = dataclasses.replace(cfg, param_dtype="bfloat16",
-                                  scan_layers=(shape.kind != "decode"))
+        cfg = dataclasses.replace(cfg, param_dtype="bfloat16")
     for k, v in spec.get("cfg", {}).items():
         cfg = dataclasses.replace(cfg, **{k: v})
     if "moe" in spec and cfg.moe is not None:
@@ -145,7 +144,7 @@ def run_variant(arch: str, shape_name: str, mesh_name: str, variant: str) -> dic
         else:
             params_sh = shd.param_shardings(axes, mesh, params_abs)
             caches_abs = abstract_caches(model, shape)
-            caches_sh = shd.cache_shardings(caches_abs, mesh, cfg, shape.global_batch)
+            caches_sh = shd.cache_shardings(caches_abs, mesh)
             batch_sh = shd.batch_shardings(batch, mesh)
             compiled = jax.jit(
                 model.decode_step,

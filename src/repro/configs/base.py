@@ -98,7 +98,6 @@ class ModelConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     remat: bool = True
-    scan_layers: bool = True  # False: unroll (decode — per-layer cache aliasing)
     sequence_parallel: bool = True  # shard saved residuals over `model` (SP)
     max_seq_len: int = 524288
 

@@ -99,11 +99,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force: bool = False) -
     if shape.kind != "train":
         # serving runs bf16 weights (deployment standard); training keeps
         # fp32 masters with ZeRO/FSDP sharding of params + optimizer state.
-        # Decode unrolls layers so every cache aliases in place.
         import dataclasses as _dc
 
-        cfg = _dc.replace(cfg, param_dtype="bfloat16",
-                          scan_layers=(shape.kind != "decode"))
+        cfg = _dc.replace(cfg, param_dtype="bfloat16")
     model = build_model(cfg)
     t0 = time.time()
     try:
@@ -151,7 +149,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force: bool = False) -
             else:  # decode
                 params_sh = shd.param_shardings(axes, mesh, params_abs)
                 caches_abs = abstract_caches(model, shape)
-                caches_sh = shd.cache_shardings(caches_abs, mesh, cfg, shape.global_batch)
+                caches_sh = shd.cache_shardings(caches_abs, mesh)
                 batch_sh = shd.batch_shardings(batch, mesh)
                 lowered = jax.jit(
                     model.decode_step,

@@ -7,7 +7,12 @@ Three execution paths:
     TPU and is validated against it.
   * decode: single-token attention against a KV cache.  Sliding-window
     layers keep a ring buffer of ``window`` entries (O(window) memory at
-    524k contexts); full-attention layers keep the whole context.
+    524k contexts); full-attention layers keep the whole context.  Decode
+    caches are layer-stacked (a leading layer axis on every leaf); a GQA
+    cache holds K and V side by side in one head-major ``[r, B, Kv, L, 2*D]``
+    array, the layout the decode einsums read.  Each step writes one entry
+    per row into its layer in place and reads K and V straight out of the
+    stack (``layer_slice``), so no layer is copied out or written back.
   * MLA decode uses the absorbed formulation and caches only the latent
     KV (+ decoupled RoPE keys) — the compression that makes MiniCPM3 cheap.
 
@@ -43,36 +48,88 @@ NEG_INF = -1e30
 # --------------------------------------------------------------------------
 
 
-def _gqa_scores(q, k):
-    """q [B,Sq,H,D], k [B,Sk,Kv,D] -> scores [B,Kv,G,Sq,Sk] (G = H // Kv)."""
+def _gqa_scores(q, k, kv_spec):
+    """q [B,Sq,H,D], k [B,Sk,Kv,D] (``kv_spec`` "bskd") or head-major
+    [B,Kv,Sk,D] ("bksd") -> scores [B,Kv,G,Sq,Sk] (G = H // Kv)."""
     b, sq, h, d = q.shape
-    kv = k.shape[2]
+    kv = k.shape[kv_spec.index("k")]
     q = q.reshape(b, sq, kv, h // kv, d)
-    return jnp.einsum("bqkgd,bskd->bkgqs", q, k, preferred_element_type=jnp.float32)
+    return jnp.einsum(f"bqkgd,{kv_spec}->bkgqs", q, k, preferred_element_type=jnp.float32)
 
 
-def _gqa_out(probs, v):
-    """probs [B,Kv,G,Sq,Sk], v [B,Sk,Kv,D] -> out [B,Sq,H,D].
+def _gqa_out(probs, v, kv_spec):
+    """probs [B,Kv,G,Sq,Sk], v laid out as ``kv_spec`` -> out [B,Sq,H,D].
 
     probs arrive in the compute dtype (bf16 on TPU) — storing fp32
     probabilities doubles the dominant HBM stream of the XLA attention
     path; accumulation stays fp32 via preferred_element_type."""
     b, kv, g, sq, sk = probs.shape
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v, preferred_element_type=jnp.float32)
+    if kv_spec == "bskd":
+        out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v, preferred_element_type=jnp.float32)
+    else:  # XLA-CPU has no bf16 x bf16 -> f32 dot with the output in that order
+        out = jnp.einsum(
+            f"bkgqs,{kv_spec}->bkgqd", probs, v, preferred_element_type=jnp.float32
+        ).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, sq, kv * g, v.shape[-1])
 
 
-def masked_attention(q, k, v, mask, scale):
+def masked_attention(q, k, v, mask, scale, *, heads_first: bool = False):
     """Softmax attention with additive mask; fp32 softmax reduction, compute-
     dtype probabilities (the Pallas flash kernel keeps them in VMEM only).
 
+    k, v are [B, Sk, Kv, D], or head-major [B, Kv, Sk, D] (``heads_first``,
+    the decode cache's layout).
     mask: broadcastable to [B, 1, 1, Sq, Sk] boolean (True = attend).
     """
-    scores = _gqa_scores(q, k) * scale
+    kv_spec = "bksd" if heads_first else "bskd"
+    scores = _gqa_scores(q, k, kv_spec) * scale
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = _gqa_out(probs, v)
+    out = _gqa_out(probs, v, kv_spec)
     return out.astype(q.dtype)
+
+
+def layer_slice(stack, layer, lo: int = 0, size: int | None = None):
+    """Layer ``layer`` of a layer-stacked cache leaf, optionally only the
+    entries ``[lo, lo + size)`` of its last axis.  Give each consumer its own
+    slice: the compiler then reads it inside the consumer's fusion, straight
+    from the stack, where a slice shared by two consumers is copied out."""
+    size = stack.shape[-1] - lo if size is None else size
+    start = (layer,) + (0,) * (stack.ndim - 2) + (lo,)
+    shape = (1,) + stack.shape[1:-1] + (size,)
+    return jax.lax.dynamic_slice(stack, start, shape, allow_negative_indices=False)[0]
+
+
+def _pos_write(cache_pos, layer, pos, slot):
+    """Record each row's new position at its ring slot of layer ``layer`` of
+    ``cache_pos`` [r, B, L]: a select over the layer's (int32, one entry per
+    slot) positions, where ``ring_write`` would be one op a row."""
+    length = cache_pos.shape[-1]
+    hit = jnp.arange(length)[None] == jnp.broadcast_to(slot, pos.shape)[:, None]
+    row = jnp.where(hit, pos[:, None].astype(jnp.int32), layer_slice(cache_pos, layer))
+    return jax.lax.dynamic_update_index_in_dim(cache_pos, row, layer, 0)
+
+
+def ring_write(cache, new, layer, slot, axis: int):
+    """Write ``new`` (one layer's entries, batch on axis 0, size 1 along
+    ``axis``) into layer ``layer`` of the layer-stacked ``cache`` at ring
+    ``slot`` along ``axis`` of the layer, in place.
+
+    A scalar ``slot`` (lockstep decode) writes the whole batch with one
+    ``dynamic_update_slice``; a ``slot`` [B] (continuous batching: every row
+    at its own position) writes each row with its own, the same form on
+    every backend (XLA-CPU would promote a bf16 scatter to fp32 and rewrite
+    the cache)."""
+    new = new.astype(cache.dtype)[None]
+    start = [layer] + [0] * (cache.ndim - 1)
+    if jnp.ndim(slot) == 0:
+        start[axis + 1] = slot
+        return jax.lax.dynamic_update_slice(cache, new, start, allow_negative_indices=False)
+    for b in range(cache.shape[1]):
+        start[1], start[axis + 1] = b, jax.lax.index_in_dim(slot, b, keepdims=False)
+        row = jax.lax.slice_in_dim(new, b, b + 1, axis=1)
+        cache = jax.lax.dynamic_update_slice(cache, row, start, allow_negative_indices=False)
+    return cache
 
 
 def blockwise_attention(q, k, v, *, causal: bool, window: int, q_offset, scale, q_chunk: int = 4096):
@@ -151,14 +208,16 @@ def attention_init(init: Initializer, cfg: ModelConfig, dtype):
 
 
 def init_attention_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=jnp.bfloat16):
-    """KV cache for one attention layer.  SWA layers use a ring buffer."""
+    """KV cache for one attention layer: K and V side by side along the last
+    axis of one head-major ``[B, Kv, L, 2*D]`` array, so a decode step writes
+    both with one op a row (decode stacks one per layer, ``init_stack_cache``).
+    SWA layers use a ring buffer."""
     h = cfg.head_dim
     length = seq_len
     if cfg.attn_type == "swa" and cfg.sliding_window:
         length = min(seq_len, cfg.sliding_window)
     return {
-        "k": jnp.zeros((batch, length, cfg.n_kv_heads, h), dtype),
-        "v": jnp.zeros((batch, length, cfg.n_kv_heads, h), dtype),
+        "kv": jnp.zeros((batch, cfg.n_kv_heads, length, 2 * h), dtype),
         "pos": jnp.full((batch, length), -1, jnp.int32),
     }
 
@@ -173,14 +232,23 @@ def attention_apply(
     update_cache: bool = False,
     impl: str = "xla",
     ragged: bool = False,
+    layer=None,
 ):
-    """Returns (out [B,S,D], new_cache)."""
+    """Returns (out [B,S,D], new_cache).  A decode ``cache`` is layer-stacked
+    and this call reads and writes its layer ``layer``."""
     compute = x.dtype
     b, s, _ = x.shape
     h = cfg.head_dim
-    q = (x @ params["w_q"].astype(compute)).reshape(b, s, cfg.n_heads, h)
-    k = (x @ params["w_k"].astype(compute)).reshape(b, s, cfg.n_kv_heads, h)
-    v = (x @ params["w_v"].astype(compute)).reshape(b, s, cfg.n_kv_heads, h)
+    q, k, v = (x @ params[w].astype(compute) for w in ("w_q", "w_k", "w_v"))
+    if cache is not None:
+        # decode: a barrier keeps each projection in the layout of its dot.
+        # Without it the TPU compiler picks the layout that makes the head
+        # split below free, and to get it copies every layer's weight into
+        # a transposed layout on every step.
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
+    q = q.reshape(b, s, cfg.n_heads, h)
+    k = k.reshape(b, s, cfg.n_kv_heads, h)
+    v = v.reshape(b, s, cfg.n_kv_heads, h)
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.norm_eps)
         k = rms_norm(k, params["k_norm"], cfg.norm_eps)
@@ -208,49 +276,27 @@ def attention_apply(
                 "pos": positions.astype(jnp.int32),
             }
     else:
-        # decode: s == 1, write into (ring) cache then attend.
-        #
-        # Lockstep mode (``ragged=False``, the one-shot ServingEngine
-        # contract): the batch advances together, so the write is one
-        # dynamic_update_slice at a scalar slot — a scatter here gets
-        # promoted to fp32 by XLA-CPU float normalization, materialising
-        # fp32 copies of the whole cache.
-        #
-        # Ragged mode (continuous batching): every row sits at its own
-        # absolute position, so each row writes its own ring slot.  A
-        # per-row one-hot select keeps it a fusable select (not a scatter,
-        # which hits the same fp32-normalization trap as above).
+        # decode: s == 1, write into (ring) cache then attend.  Lockstep mode
+        # (``ragged=False``, the one-shot ServingEngine contract) advances
+        # the batch together, so all rows share one ring slot; ragged mode
+        # (continuous batching) writes each row at its own slot.
         assert s == 1, "decode path expects a single new token"
         pos = positions[:, 0]  # [B]
-        length = cache["k"].shape[1]
-        if ragged:
-            hit = (pos[:, None] % length) == jnp.arange(length)[None]  # [B, L]
-            ck = jnp.where(hit[:, :, None, None], k.astype(cache["k"].dtype), cache["k"])
-            cv = jnp.where(hit[:, :, None, None], v.astype(cache["v"].dtype), cache["v"])
-            cpos = jnp.where(hit, pos[:, None].astype(jnp.int32), cache["pos"])
-        else:
-            slot = (pos[0] % length).astype(jnp.int32)
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), slot, axis=1
-            )
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), slot, axis=1
-            )
-            cpos = jax.lax.dynamic_update_slice_in_dim(
-                cache["pos"], pos[:, None].astype(jnp.int32), slot, axis=1
-            )
-        delta = pos[:, None] - cpos  # [B, L]
-        valid = (cpos >= 0) & (delta >= 0)
+        slot = (pos % cache["kv"].shape[3]).astype(jnp.int32)
+        if not ragged:
+            slot = slot[0]
+        new_kv = jnp.concatenate([k, v], axis=-1).swapaxes(1, 2)  # [B, Kv, 1, 2D]
+        ckv = ring_write(cache["kv"], new_kv, layer, slot, axis=2)
+        cpos = _pos_write(cache["pos"], layer, pos, slot)
+        delta = pos[:, None] - layer_slice(cpos, layer)  # [B, L]
+        valid = (delta >= 0) & (layer_slice(cpos, layer) >= 0)
         if window > 0:
             valid &= delta < window
         mask = valid[:, None, None, None, :]  # [B,1,1,1,L]
-        # the barrier pins any dtype conversion of the cache *inside* the
-        # layer scan: without it XLA hoists convert(dynamic-slice(xs)) into
-        # dynamic-slice(convert(xs)), materialising an fp32 copy of the
-        # full multi-layer KV cache
-        ku, vu = jax.lax.optimization_barrier((ck, cv))
-        out = masked_attention(q, ku.astype(compute), vu.astype(compute), mask, scale)
-        new_cache = {"k": ck, "v": cv, "pos": cpos}
+        k_l = layer_slice(ckv, layer, 0, h).astype(compute)
+        v_l = layer_slice(ckv, layer, h, h).astype(compute)
+        out = masked_attention(q, k_l, v_l, mask, scale, heads_first=True)
+        new_cache = {"kv": ckv, "pos": cpos}
 
     out = out.reshape(b, s, cfg.n_heads * h)
     return out @ params["w_o"].astype(compute), new_cache
@@ -326,6 +372,7 @@ def mla_apply(
     update_cache: bool = False,
     impl: str = "xla",
     ragged: bool = False,
+    layer=None,
 ):
     m = cfg.mla
     compute = x.dtype
@@ -348,37 +395,26 @@ def mla_apply(
         # absorbed decode: score = q_nope W_uk^T . ckv + q_rope . k_rope
         assert s == 1
         pos = positions[:, 0]
-        length = cache["ckv"].shape[1]
-        if ragged:
-            # per-row ring slot (continuous batching) — see attention_apply
-            hit = (pos[:, None] % length) == jnp.arange(length)[None]  # [B, L]
-            cckv = jnp.where(hit[:, :, None], ckv.astype(cache["ckv"].dtype), cache["ckv"])
-            ckrope = jnp.where(
-                hit[:, :, None], k_rope.astype(cache["k_rope"].dtype), cache["k_rope"]
-            )
-            cpos = jnp.where(hit, pos[:, None].astype(jnp.int32), cache["pos"])
-        else:
-            slot = (pos[0] % length).astype(jnp.int32)
-            cckv = jax.lax.dynamic_update_slice_in_dim(
-                cache["ckv"], ckv.astype(cache["ckv"].dtype), slot, axis=1
-            )
-            ckrope = jax.lax.dynamic_update_slice_in_dim(
-                cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), slot, axis=1
-            )
-            cpos = jax.lax.dynamic_update_slice_in_dim(
-                cache["pos"], pos[:, None].astype(jnp.int32), slot, axis=1
-            )
+        slot = (pos % cache["ckv"].shape[2]).astype(jnp.int32)
+        if not ragged:  # lockstep: one shared slot (see attention_apply)
+            slot = slot[0]
+        cckv = ring_write(cache["ckv"], ckv, layer, slot, axis=1)
+        ckrope = ring_write(cache["k_rope"], k_rope, layer, slot, axis=1)
+        cpos = _pos_write(cache["pos"], layer, pos, slot)
         w_uk = params["w_uk"].astype(compute).reshape(m.kv_lora_rank, nh, m.qk_nope_head_dim)
         q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)  # [B,1,H,rank]
         scores = jnp.einsum(
-            "bshr,blr->bhsl", q_lat, cckv.astype(compute), preferred_element_type=jnp.float32
+            "bshr,blr->bhsl", q_lat, layer_slice(cckv, layer).astype(compute),
+            preferred_element_type=jnp.float32,
         ) + jnp.einsum(
-            "bshd,bld->bhsl", q_rope, ckrope.astype(compute), preferred_element_type=jnp.float32
+            "bshd,bld->bhsl", q_rope, layer_slice(ckrope, layer).astype(compute),
+            preferred_element_type=jnp.float32,
         )
-        valid = (cpos >= 0) & (pos[:, None] >= cpos)
+        lpos = layer_slice(cpos, layer)
+        valid = (lpos >= 0) & (pos[:, None] >= lpos)
         scores = jnp.where(valid[:, None, None, :], scores * scale, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
-        o_lat = jnp.einsum("bhsl,blr->bshr", probs, cckv.astype(jnp.float32))  # [B,1,H,rank]
+        o_lat = jnp.einsum("bhsl,blr->bshr", probs, layer_slice(cckv, layer).astype(jnp.float32))  # [B,1,H,rank]
         w_uv = params["w_uv"].astype(compute).reshape(m.kv_lora_rank, nh, m.v_head_dim)
         out = jnp.einsum("bshr,rhd->bshd", o_lat.astype(compute), w_uv)
         new_cache = {"ckv": cckv, "k_rope": ckrope, "pos": cpos}

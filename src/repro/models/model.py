@@ -219,7 +219,8 @@ class Model:
         return tuple(fix(dict(e)) for e in caches)
 
     def prepare_decode_caches(self, caches, capacity: int):
-        """Re-lay prefill caches into decode (ring) buffers with headroom.
+        """Re-lay prefill caches into decode (ring) buffers with headroom,
+        layer-stacked (the layout of :meth:`init_cache`).
 
         Full-attention layers get ``capacity`` slots (entry at slot
         pos % capacity); SWA layers keep ``min(capacity, window)`` most
@@ -232,7 +233,9 @@ class Model:
             cap = capacity
             if "k" in c and cfg.attn_type == "swa" and cfg.sliding_window:
                 cap = min(capacity, cfg.sliding_window)
-            names = ("k", "v") if "k" in c else ("ckv", "k_rope")
+            if "k" in c:  # GQA: K and V side by side, as the decode cache holds them
+                c = {"kv": jnp.concatenate([c["k"], c["v"]], axis=-1), "pos": c["pos"]}
+            names = ("kv",) if "kv" in c else ("ckv", "k_rope")
             pos = c["pos"]  # [..., B, L]
             max_pos = jnp.max(pos, axis=-1, keepdims=True)
             keep = (pos >= 0) & (pos > max_pos - cap)
@@ -249,6 +252,8 @@ class Model:
                 return fn(slot, arr)
 
             out = {n: scatter_one(c[n], 0) for n in names}
+            if "kv" in c:  # [.., B, L, Kv, 2D] -> head-major [.., B, Kv, L, 2D]
+                out["kv"] = out["kv"].swapaxes(-3, -2)
             out["pos"] = scatter_one(jnp.where(keep, pos, -1), -1)
             return out
 
@@ -258,7 +263,7 @@ class Model:
                 out["mixer"] = relay_mixer(out["mixer"])
             return out
 
-        return tuple(relay_block(bc) for bc in caches)
+        return tf.stack_layers(tuple(relay_block(bc) for bc in caches), cfg.n_layers)
 
     def decode_step(self, params, caches, tokens, pos, impl: str = "xla", mesh=None,
                     ragged: bool = False):

@@ -2,9 +2,12 @@
 
 A model is a repeated *pattern* of blocks (the smallest period of the
 (mixer, ffn) layer spec — 1 for uniform models, 8 for Jamba's 1:7
-Mamba/attention interleave).  The stack scans over pattern repeats
-(`lax.scan`) so compile time and HLO size are O(pattern), with optional
-rematerialisation per repeat.
+Mamba/attention interleave).  Training and prefill scan over pattern
+repeats (`lax.scan`) so compile time and HLO size are O(pattern), with
+optional rematerialisation per repeat.  Decode caches are layer-stacked
+too (leaves ``[r, B, ...]``), and decode loops over the repeats with the
+whole stack in the loop's carry: each layer writes its new entries into the
+stack in place and attention reads its layer straight out of it.
 
 Block = norm -> mixer (attention | MLA | SSM) [+ cross-attention for
 decoders] -> residual -> norm -> FFN (dense SwiGLU | MoE) -> residual.
@@ -25,7 +28,10 @@ from . import moe as moe_mod
 from . import ssm as ssm_mod
 from .layers import Initializer, mlp_apply, mlp_init, rms_norm
 
-__all__ = ["block_init", "block_apply", "stack_init", "stack_apply", "init_stack_cache"]
+__all__ = [
+    "block_init", "block_apply", "stack_init", "stack_apply", "init_stack_cache",
+    "stack_layers",
+]
 
 
 def constrain_residual(x: jax.Array, cfg: ModelConfig, mesh=None) -> jax.Array:
@@ -143,11 +149,17 @@ def block_apply(
     key=None,
     mesh=None,
     ragged=False,
+    layer=None,
 ):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux).  With ``layer`` (decode) every leaf of
+    ``cache`` is layer-stacked and the block reads and writes layer
+    ``layer``: attention in place, the other (small) caches sliced out and
+    written back."""
     kind = _mixer_kind(cfg, j, encoder)
     h = rms_norm(x, params["ln1"], cfg.norm_eps)
     mixer_cache = cache.get("mixer") if cache else None
+    if layer is not None and kind == "ssm":
+        mixer_cache = jax.tree.map(lambda t: attn_mod.layer_slice(t, layer), mixer_cache)
     if kind == "attn":
         if encoder or not causal:
             out = attn_mod.blockwise_attention(
@@ -163,23 +175,31 @@ def block_apply(
         else:
             out, new_mixer_cache = attn_mod.attention_apply(
                 params["mixer"], h, cfg, positions=positions, cache=mixer_cache,
-                update_cache=update_cache, impl=impl, ragged=ragged,
+                update_cache=update_cache, impl=impl, ragged=ragged, layer=layer,
             )
     elif kind == "mla":
         out, new_mixer_cache = attn_mod.mla_apply(
             params["mixer"], h, cfg, positions=positions, cache=mixer_cache,
-            update_cache=update_cache, impl=impl, ragged=ragged,
+            update_cache=update_cache, impl=impl, ragged=ragged, layer=layer,
         )
     else:
         out, new_mixer_cache = ssm_mod.ssm_apply(
             params["mixer"], h, cfg, positions=positions, cache=mixer_cache,
             update_cache=update_cache, impl=impl,
         )
+        if layer is not None:
+            new_mixer_cache = jax.tree.map(
+                lambda t, n: jax.lax.dynamic_update_index_in_dim(t, n.astype(t.dtype), layer, 0),
+                cache["mixer"], new_mixer_cache,
+            )
     x = x + out
 
     if "cross" in params:
         hc = rms_norm(x, params["ln_cross"], cfg.norm_eps)
-        x = x + _cross_attention(params["cross"], hc, cache["cross"], cfg, x.dtype)
+        memory_kv = cache["cross"]
+        if layer is not None:
+            memory_kv = jax.tree.map(lambda t: attn_mod.layer_slice(t, layer), memory_kv)
+        x = x + _cross_attention(params["cross"], hc, memory_kv, cfg, x.dtype)
 
     aux = jnp.zeros((), jnp.float32)
     if "ffn" in params:
@@ -256,26 +276,30 @@ def stack_axes(cfg: ModelConfig, *, n_layers=None, encoder=False, cross=False):
 
 def init_stack_cache(cfg: ModelConfig, batch: int, seq_len: int, *, n_layers=None, cross=False,
                      mem_len=0, dtype=jnp.bfloat16):
+    """Decode caches, one entry per pattern position, every leaf stacked over
+    the pattern's ``r`` repeats (``[r, B, ...]``, ``r`` >= 1): the carry of
+    the decode loop in ``stack_apply``, donated and updated in place."""
     n_layers = n_layers or cfg.n_layers
-    if not cfg.scan_layers:
-        # unrolled layout: one (donatable, individually aliased) cache per layer
-        return tuple(
-            init_block_cache(cfg, j % cfg.n_layers, batch, seq_len, cross=cross,
-                             mem_len=mem_len, dtype=dtype)
-            for j in range(n_layers)
-        )
     p = _stack_period(cfg, n_layers, False)
     r = n_layers // p
-    pattern = []
-    for j in range(p):
-        caches = [
-            init_block_cache(cfg, j, batch, seq_len, cross=cross, mem_len=mem_len, dtype=dtype)
-            for _ in range(r)
-        ]
-        pattern.append(
-            jax.tree.map(lambda *xs: jnp.stack(xs), *caches) if r > 1 else caches[0]
-        )
-    return tuple(pattern)
+
+    def stack(t):
+        return jnp.broadcast_to(t, (r,) + t.shape)
+
+    return tuple(
+        jax.tree.map(stack, init_block_cache(cfg, j, batch, seq_len, cross=cross,
+                                             mem_len=mem_len, dtype=dtype))
+        for j in range(p)
+    )
+
+
+def stack_layers(caches: tuple, n_layers: int) -> tuple:
+    """Caches as a scanned prefill emits them (one entry per pattern
+    position, leaves ``[r, B, ...]`` only where the pattern repeats ``r > 1``
+    times) with the leading repeat axis that decode caches always carry."""
+    if n_layers // len(caches) > 1:
+        return tuple(caches)
+    return tuple(jax.tree.map(lambda t: t[None], c) for c in caches)
 
 
 def stack_apply(
@@ -298,26 +322,32 @@ def stack_apply(
     p = len(pattern_params)
     r = n_layers // p
 
-    if caches is not None and len(caches) == n_layers and (not cfg.scan_layers or r == 1):
-        # unrolled layout: per-layer caches, static indexing into the
-        # (possibly repeat-stacked) params — used by decode so each layer's
-        # cache input aliases its output (in-place DUS, no while-carry
-        # double buffering)
-        aux = jnp.zeros((), jnp.float32)
-        new_caches = []
-        for i in range(n_layers):
-            rep, j = divmod(i, p)
-            layer_params = pattern_params[j]
-            if r > 1:
-                layer_params = jax.tree.map(lambda t: t[rep], layer_params)
-            x, nc, a = block_apply(
-                layer_params, x, cfg, j, positions=positions, cache=caches[i],
-                update_cache=update_cache, encoder=encoder, impl=impl, key=key, mesh=mesh,
-                ragged=ragged,
-            )
-            aux = aux + a
-            new_caches.append(nc if nc is not None else {})
-        return x, tuple(new_caches), aux
+    if caches is not None and "mixer" in caches[0]:
+        # decode (only decode caches hold the mixers' state; training and
+        # prefill take none, or an encoder's cross K/V alone): the
+        # layer-stacked caches ride in the loop's carry, so each layer writes
+        # its entries into the donated stack in place and reads its K/V
+        # straight out of it; a repeat's params are sliced from the stacked
+        # params inside the ops that read them
+        def layer(rep, carry):
+            h, aux, stacks = carry
+            new = []
+            for j in range(p):
+                lp = pattern_params[j]
+                if r > 1:
+                    lp = jax.tree.map(lambda t: t[rep], lp)
+                h, nc, a = block_apply(
+                    lp, h, cfg, j, positions=positions, cache=stacks[j], encoder=encoder,
+                    impl=impl, key=key, mesh=mesh, ragged=ragged, layer=rep,
+                )
+                aux = aux + a
+                new.append(nc)
+            return h, aux, tuple(new)
+
+        carry = (x, jnp.zeros((), jnp.float32), tuple(caches))
+        x, aux, new_caches = (layer(0, carry) if r == 1
+                              else jax.lax.fori_loop(0, r, layer, carry))
+        return x, new_caches, aux
 
     def body(carry, xs):
         h, aux = carry

@@ -219,16 +219,17 @@ class RequestQueue:
 # --------------------------------------------------------------------------
 
 
-def merge_slot_caches(pool_caches, one_caches, slot, stacked: bool):
+ROWS = 1  # the rows (slots) axis of every decode-cache leaf: [r, B, ...]
+
+
+def merge_slot_caches(pool_caches, one_caches, slot):
     """Write a single-request decode cache (batch dim 1) into row ``slot`` of
-    the pooled cache.  Pure — composes into jitted prefill.  ``stacked`` says
-    whether cache leaves carry a leading scan-repeat dim ([r, B, ...]) so the
-    batch axis is 1 instead of 0."""
-    ax = 1 if stacked else 0
+    the pooled cache.  Pure — composes into jitted prefill."""
 
     def write(pool_leaf, one_leaf):
         return jax.lax.dynamic_update_slice_in_dim(
-            pool_leaf, one_leaf.astype(pool_leaf.dtype), slot, axis=ax
+            pool_leaf, one_leaf.astype(pool_leaf.dtype), slot, axis=ROWS,
+            allow_negative_indices=False,
         )
 
     return jax.tree.map(write, pool_caches, one_caches)
@@ -239,13 +240,15 @@ class KVPool:
     evicted (freed + reused) on completion.
 
     The pooled cache is the model's native decode layout with batch dim
-    ``n_slots``; each slot holds ``capacity`` ring entries (sliding-window
-    layers hold ``min(capacity, window)`` — same rule as
-    ``Model.prepare_decode_caches``).  Freed slots are reused LIFO so a hot
-    cache row is recycled immediately.
+    ``n_slots``: layer-stacked, rows on axis ``ROWS`` (1) of every leaf (GQA
+    K and V side by side, head-major ``[r, n_slots, Kv, L, 2*D]``).  Each slot
+    holds ``capacity`` ring entries (sliding-window layers hold
+    ``min(capacity, window)`` — same rule as ``Model.prepare_decode_caches``).
+    Freed slots are reused LIFO so a hot cache row is recycled immediately.
     """
 
     tiered = False  # TieredKVPool overrides; engines branch on this
+    stacked = True  # every leaf carries a leading layer axis: rows are axis ROWS
 
     def __init__(self, model: Model, n_slots: int, capacity: int):
         if n_slots < 1:
@@ -254,8 +257,6 @@ class KVPool:
         self.n_slots = n_slots
         self.capacity = capacity
         self.caches = model.init_cache(n_slots, capacity)
-        cfg = model.cfg
-        self.stacked = cfg.scan_layers and (cfg.n_layers // max(len(self.caches), 1)) > 1
         self._free: list[int] = list(range(n_slots - 1, -1, -1))  # pop() -> slot 0 first
         self.slot_rid: list[Optional[int]] = [None] * n_slots
         self.n_alloc = 0
@@ -263,12 +264,10 @@ class KVPool:
         self.high_water = 0
         # the slot-write jit is shared across pools of the same layout, so a
         # migrated/rebuilt pool pays no recompile to re-insert its rows
-        key = ("kvpool_write", model, n_slots, capacity, self.stacked)
+        key = ("kvpool_write", model, n_slots, capacity)
         self._write = _JIT_CACHE.get(key)
         if self._write is None:
-            self._write = jax.jit(
-                partial(merge_slot_caches, stacked=self.stacked), donate_argnums=0
-            )
+            self._write = jax.jit(merge_slot_caches, donate_argnums=0)
             _JIT_CACHE[key] = self._write
 
     @property
@@ -323,11 +322,7 @@ class KVPool:
         re-inserted into a pool living on any survivor mesh, bit-exact."""
         if self.slot_rid[slot] is None:
             raise ValueError(f"slot {slot} is not allocated")
-        ax = 1 if self.stacked else 0
-        return jax.tree.map(
-            lambda c: np.asarray(jax.lax.slice_in_dim(c, slot, slot + 1, axis=ax)),
-            self.caches,
-        )
+        return jax.tree.map(lambda c: np.asarray(c[:, slot : slot + 1]), self.caches)
 
     def insert(self, slot: int, row) -> None:
         """Install an extracted row into (allocated) ``slot`` — the inverse
@@ -347,13 +342,12 @@ class KVPool:
                 raise ValueError(f"slot {s} is not allocated")
         if not slots:
             return []
-        ax = 1 if self.stacked else 0
         idx = jnp.asarray(list(slots), jnp.int32)
         gathered = jax.device_get(
-            jax.tree.map(lambda c: jnp.take(c, idx, axis=ax), self.caches)
+            jax.tree.map(lambda c: jnp.take(c, idx, axis=ROWS), self.caches)
         )
         return [
-            jax.tree.map(lambda c: np.take(c, [i], axis=ax), gathered)
+            jax.tree.map(lambda c: np.take(c, [i], axis=ROWS), gathered)
             for i in range(len(slots))
         ]
 
@@ -368,24 +362,19 @@ class KVPool:
         for s in slots:
             if self.slot_rid[s] is None:
                 raise ValueError(f"slot {s} is not allocated — allocate before insert")
-        ax = 1 if self.stacked else 0
-        packed = jax.tree.map(lambda *ls: np.concatenate(ls, axis=ax), *rows)
-        key = ("kvpool_write_many", self.model, self.n_slots, self.capacity,
-               self.stacked, len(slots))
+        packed = jax.tree.map(lambda *ls: np.concatenate(ls, axis=ROWS), *rows)
+        key = ("kvpool_write_many", self.model, self.n_slots, self.capacity, len(slots))
         write_many = _JIT_CACHE.get(key)
         if write_many is None:
-            k, stacked = len(slots), self.stacked
+            k = len(slots)
 
             @partial(jax.jit, donate_argnums=0)
             def write_many(pool_caches, packed_rows, slot_idx):
                 for i in range(k):
                     row = jax.tree.map(
-                        lambda c: jax.lax.dynamic_slice_in_dim(c, i, 1, axis=ax),
-                        packed_rows,
+                        lambda c: jax.lax.slice_in_dim(c, i, i + 1, axis=ROWS), packed_rows
                     )
-                    pool_caches = merge_slot_caches(
-                        pool_caches, row, slot_idx[i], stacked
-                    )
+                    pool_caches = merge_slot_caches(pool_caches, row, slot_idx[i])
                 return pool_caches
 
             _JIT_CACHE[key] = write_many
@@ -989,8 +978,7 @@ class ContinuousBatchingEngine:
         migration or restart that lands back on a previously-seen
         (model, mesh, pool) configuration pays no recompile."""
         return (
-            self.model, self.mesh, self.pool.n_slots, self.pool.capacity,
-            self.pool.stacked, self.seed,
+            self.model, self.mesh, self.pool.n_slots, self.pool.capacity, self.seed,
         )
 
     def _build_jits(self) -> None:
@@ -1020,9 +1008,6 @@ class ContinuousBatchingEngine:
 
         # sampling is fused into the prefill/decode jits: one dispatch per
         # serving step, tokens (not logits) cross the host boundary
-        stacked = self.pool.stacked
-        row_axis = 1 if stacked else 0
-
         @partial(jax.jit, donate_argnums=(3,))
         def prefill_into(params, tokens, true_len, pool_caches, slots, temps, rids,
                          idx0):
@@ -1038,10 +1023,8 @@ class ContinuousBatchingEngine:
             caches = m.mask_prompt_cache(caches, true_len)
             caches = m.prepare_decode_caches(caches, capacity=max_len)
             for i in range(g):
-                row = jax.tree.map(
-                    lambda c: jax.lax.dynamic_slice_in_dim(c, i, 1, axis=row_axis), caches
-                )
-                pool_caches = merge_slot_caches(pool_caches, row, slots[i], stacked)
+                row = jax.tree.map(lambda c: jax.lax.slice_in_dim(c, i, i + 1, axis=ROWS), caches)
+                pool_caches = merge_slot_caches(pool_caches, row, slots[i])
             toks = jax.vmap(sample_one)(logits[:, 0], temps, rids, idx0)
             return toks, pool_caches
 
@@ -1669,7 +1652,8 @@ class ServingEngine:
         mesh = self.mesh
         self._prefill = jax.jit(lambda p, b: self.model.prefill(p, b, mesh=mesh))
         self._decode = jax.jit(
-            lambda p, c, t, pos: self.model.decode_step(p, c, t, pos, mesh=mesh)
+            lambda p, c, t, pos: self.model.decode_step(p, c, t, pos, mesh=mesh),
+            donate_argnums=(1,),
         )
 
     def generate(

@@ -111,40 +111,38 @@ def batch_shardings(batch_tree, mesh):
     return jax.tree.map(leaf, batch_tree)
 
 
-def cache_shardings(cache_tree, mesh, cfg, batch: int):
-    """Decode caches, walked by name: batch over DP when divisible; KV heads
-    over ``model`` when divisible, else the sequence axis (split-KV decode
-    for long contexts / small batch); SSM heads/channels over ``model``."""
+def cache_shardings(cache_tree, mesh):
+    """Decode caches (layer-stacked: a leading layer axis, rows on axis 1),
+    walked by name: batch over DP when divisible; KV heads over ``model``
+    when divisible, else the sequence axis (split-KV decode for long
+    contexts / small batch); SSM heads/channels over ``model``.  Self-
+    attention ``kv`` is head-major ``[r, B, Kv, L, 2*D]``; cross-attention
+    memory ``k``/``v`` is ``[r, B, S, Kv, D]``."""
     dp = _dp(mesh)
     dpn = _dp_size(mesh)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     mp = sizes.get("model", 1)
 
     def spec(name: str, arr) -> NamedSharding:
-        lead = 0 if (arr.ndim and arr.shape[0] == batch) else 1  # scan axis?
         entries: list = [None] * arr.ndim
-        bdim = lead
-        if dp and arr.shape[bdim] % dpn == 0 and arr.shape[bdim] > 1:
-            entries[bdim] = dp
+        if dp and arr.shape[1] % dpn == 0 and arr.shape[1] > 1:
+            entries[1] = dp
         if mp > 1:
-            if name in ("k", "v"):
-                kvdim, sdim = bdim + 2, bdim + 1
+            if name in ("kv", "k", "v"):
+                kvdim, sdim = (2, 3) if name == "kv" else (3, 2)
                 if arr.shape[kvdim] % mp == 0:
                     entries[kvdim] = "model"
                 elif arr.shape[sdim] % mp == 0:
                     entries[sdim] = "model"  # split-KV decode
             elif name in ("ckv", "k_rope"):
-                sdim = bdim + 1
-                if arr.shape[sdim] % mp == 0:
-                    entries[sdim] = "model"
+                if arr.shape[2] % mp == 0:
+                    entries[2] = "model"
             elif name == "conv":
-                cdim = bdim + 2
-                if arr.shape[cdim] % mp == 0:
-                    entries[cdim] = "model"
+                if arr.shape[3] % mp == 0:
+                    entries[3] = "model"
             elif name == "h":
-                hdim = bdim + 1
-                if arr.shape[hdim] % mp == 0:
-                    entries[hdim] = "model"
+                if arr.shape[2] % mp == 0:
+                    entries[2] = "model"
         return NamedSharding(mesh, P(*entries))
 
     def walk(subtree):

@@ -85,9 +85,9 @@ def test_long_context_archs_have_bounded_state(arch):
     caches = model.init_cache(batch=1, seq_len=4096)
     for bc in caches:
         mixer = bc.get("mixer", {})
-        if "k" in mixer and cfg.attn_type == "swa":
+        if "kv" in mixer and cfg.attn_type == "swa":
             # ring buffer bounded by the window
-            assert mixer["k"].shape[-3] <= cfg.sliding_window
+            assert mixer["kv"].shape[-2] <= cfg.sliding_window  # [r, B, Kv, L, 2*D]
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

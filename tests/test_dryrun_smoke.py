@@ -63,14 +63,14 @@ def test_train_cell_lowers_and_compiles(arch, mesh):
 
 @pytest.mark.parametrize("arch", ["internlm2-1.8b", "jamba-v0.1-52b", "minicpm3-4b"])
 def test_decode_cell_lowers_and_compiles(arch, mesh):
-    cfg = _reduced(arch, scan_layers=False, param_dtype="bfloat16")
+    cfg = _reduced(arch, param_dtype="bfloat16")
     shape = ShapeConfig("decode_tiny", seq_len=128, global_batch=8, kind="decode")
     model = build_model(cfg)
     with use_mesh(mesh):
         params_abs = abstract_params(model)
         params_sh = shd.param_shardings(model.param_axes(), mesh, params_abs)
         caches_abs = abstract_caches(model, shape)
-        caches_sh = shd.cache_shardings(caches_abs, mesh, cfg, shape.global_batch)
+        caches_sh = shd.cache_shardings(caches_abs, mesh)
         batch = input_specs(cfg, shape)
         batch_sh = shd.batch_shardings(batch, mesh)
         compiled = jax.jit(

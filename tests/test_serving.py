@@ -4,13 +4,16 @@ policies, sampling determinism, and equivalence against the one-shot path.
 
 import dataclasses
 import time
+from functools import partial
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import get_config
 from repro.core.collectives import CollectiveCostModel
+from repro.models import attention as attn_mod
 from repro.models import build_model
 from repro.runtime.serving import (
     ContinuousBatchingEngine,
@@ -77,6 +80,71 @@ def test_kvpool_write_isolates_slots(tiny):
             if not np.array_equal(np.take(b, row, axis=ax), np.take(a, row, axis=ax)):
                 changed_rows.add(row)
     assert changed_rows == {1}  # only the written slot's row moved
+
+
+def _one_hot_write(cache, new, layer, slot, axis):
+    """Reference for the decode's ring write: a one-hot select over every
+    ring entry of every row of the layer (the write decode made before it
+    wrote each row's entry in place)."""
+    old = jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+    new = new.astype(cache.dtype)
+    b, length = old.shape[0], old.shape[axis]
+    ring = jnp.arange(length).reshape((1,) * axis + (length,) + (1,) * (old.ndim - axis - 1))
+    hit = ring == jnp.broadcast_to(slot, (b,)).reshape((b,) + (1,) * (old.ndim - 1))
+    return jax.lax.dynamic_update_index_in_dim(cache, jnp.where(hit, new, old), layer, 0)
+
+
+# arch, config overrides, ragged (False: the lockstep one-slot write).  Two
+# layers run the decode loop over a two-deep stack; one layer runs it once
+WRITE_CASES = {
+    "gqa": ("internlm2-1.8b", {}, True),
+    "gqa_one_layer": ("internlm2-1.8b", {"n_layers": 1}, True),
+    "swa_ring_wrap": ("h2o-danube-1.8b", {"sliding_window": 8}, True),
+    "mla": ("minicpm3-4b", {}, True),
+    "gqa_lockstep": ("internlm2-1.8b", {}, False),
+}
+
+
+@pytest.mark.parametrize("case", list(WRITE_CASES))
+def test_in_place_ring_write_matches_one_hot_write(case, monkeypatch):
+    """One decode step over a live bf16 pool (rows at distinct positions, past
+    the window on the SWA ring, one row a freed slot) gives bit-identical
+    logits and cache leaves whether each row's entry is written in place or
+    selected in over the whole ring."""
+    arch, overrides, ragged = WRITE_CASES[case]
+    cfg = dataclasses.replace(get_config(arch, reduced=True), **{"n_layers": 2, **overrides})
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ContinuousBatchingEngine(model, params, n_slots=4, max_len=32)
+    rng = np.random.default_rng(3)
+    for plen, budget in zip([11, 14, 5, 9], [12, 12, 2, 12]):
+        eng.submit(rng.integers(1, cfg.vocab, plen).astype(np.int32), budget)
+    for _ in range(4):
+        eng.step()
+    assert eng.pool.n_free == 1  # the two-token request is done: its slot is free
+    caches = jax.device_get(eng.pool.caches)
+    tokens = jnp.asarray(eng._tokens)[:, None]
+    pos = eng._pos.copy()
+    assert len(set(pos.tolist())) == 4
+    if not ragged:
+        pos[:] = pos.max()
+    if case == "swa_ring_wrap":
+        assert pos.max() >= 2 * cfg.sliding_window
+
+    def step(write):
+        monkeypatch.setattr(attn_mod, "ring_write", write)
+        fn = jax.jit(partial(model.decode_step, ragged=ragged))
+        return jax.device_get(fn(params, caches, tokens, jnp.asarray(pos)))
+
+    logits, new = step(attn_mod.ring_write)
+    ref_logits, ref = step(_one_hot_write)
+    np.testing.assert_array_equal(logits, ref_logits)
+    assert jax.tree.structure(new) == jax.tree.structure(ref)
+    for got, want in zip(jax.tree.leaves(new), jax.tree.leaves(ref)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+    assert any(not np.array_equal(a, b)  # the step wrote something
+               for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(caches)))
 
 
 # ---------------------------------------------------------------- scheduler

@@ -3,13 +3,17 @@
 The TPU compiler refuses what interpret mode accepts (block shapes off the
 (8, 128) tiling, too much VMEM) and what does not fit the chip's memory.
 These tests compile the three Pallas kernels with ``interpret=False`` at
-the widths of the models that use them, and the full-width internlm2-1.8b
-ragged decode step of the serving engine, for one chip of a ``v5e:2x2``
-topology.  The topology is described inside a fixture, never at import:
+the widths of the models that use them, and the full-width ragged decode
+step of the serving engine, for one chip of a ``v5e:2x2`` topology: that it
+fits, and that it writes the KV pool in place at the benchmark cells'
+shapes.  The topology is described inside a fixture, never at import:
 only one process at a time may load the TPU library.
 """
 
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -98,3 +102,91 @@ def test_internlm2_ragged_decode_fits_one_chip(topo, one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 7e9  # really the full-width fp32 model
     assert total < peaks(topo.devices[0].device_kind)["hbm_per_chip"], total
+
+
+# results that name a buffer without moving data into it
+_NO_MOVE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "conditional",
+            "call", "optimization-barrier", "constant", "copy-done", "slice-done",
+            "dynamic-slice-done", "async-done"}
+
+
+def _computations(hlo: str) -> dict:
+    """``{computation: [(instruction, result type, op, root?)]}`` of HLO text."""
+    comps, body = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head and not line.startswith(" "):
+            body = comps.setdefault(head.group(1), [])
+            continue
+        m = re.match(r"\s*(ROOT )?%(\S+) = (.+?) ([\w\-]+)\(", line)
+        if m and body is not None:
+            body.append((m.group(2), m.group(3), m.group(4), bool(m.group(1))))
+    return comps
+
+
+def _layer_sized_moves(hlo: str, elems: int) -> list[str]:
+    """Instructions that write ``elems`` elements or more into a buffer of
+    their own: copies (into any memory space, async or not), selects,
+    slices, and fusions, anywhere but inside a fusion (an op fused into
+    another reads its operand in place; a fusion's own result is what it
+    writes).  An in-place update — a ``dynamic-update-slice``, or a fusion
+    whose root is one — writes only its update and is not counted."""
+    comps = _computations(hlo)
+    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", hlo))
+    roots = {comp: op for comp, body in comps.items() for _, _, op, root in body if root}
+    calls = dict(re.findall(r"%(\S+) = .*?\bfusion\(.*?calls=%([\w.\-]+)", hlo))
+
+    def in_place(name, op):
+        if op == "fusion":
+            op = roots.get(calls.get(name), op)
+        return op == "dynamic-update-slice"
+
+    found = []
+    for comp, body in comps.items():
+        if comp in fused:
+            continue
+        for name, rtype, op, _ in body:
+            if op in _NO_MOVE or in_place(name, op):
+                continue
+            dims = re.findall(r"\w+\[([\d,]*)\]", rtype)
+            if not dims:
+                continue
+            n = 1
+            for d in filter(None, dims[0].split(",")):  # an async start opens with its result
+                n *= int(d)
+            if n >= elems:
+                found.append(f"{name} {op} [{dims[0]}]")
+    return found
+
+
+@pytest.mark.parametrize("workload", ["internlm2-1.8b.chat_open",
+                                      "h2o-danube-1.8b.longctx_decode"])
+def test_ragged_decode_writes_the_pool_in_place(one_chip, workload):
+    """The engine's bf16 ragged decode at a benchmark cell's published widths
+    and pool shape, with the pool donated: the pool aliases the output, the
+    step's temporaries stay under a tenth of the pool, and no copy, select,
+    slice or fusion writes a whole layer's K or V (or more) anywhere: each
+    layer's K and V are read where they lie in the stack."""
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from bench import spec
+    from repro.models import build_model
+    from repro.runtime.serving import ContinuousBatchingEngine
+
+    cell = spec.load_cell(workload)
+    model = build_model(spec.model_config(cell.config))
+    slots, cap = int(cell.cell["n_slots"]), int(cell.cell["max_len"])
+    engine = ContinuousBatchingEngine(model, None, n_slots=1, max_len=cap)
+    place = lambda t: jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype), t)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = place(jax.eval_shape(lambda: model.init_cache(slots, cap)))
+    rows = lambda dtype=jnp.int32: _shape(one_chip, (slots,), dtype)
+    compiled = engine._decode.lower(
+        params, pool, rows(), rows(), rows(jnp.float32), rows(), rows()
+    ).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.1 * pool_bytes, (mem.temp_size_in_bytes, pool_bytes)
+    layer_k = min(c["mixer"]["kv"].size // c["mixer"]["kv"].shape[0] // 2 for c in pool)
+    assert _layer_sized_moves(compiled.as_text(), layer_k) == []
