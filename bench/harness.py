@@ -229,18 +229,21 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t_start: float
         target.update((overrides or {}).get(key, {}))
     generator.validate(mix)
     spec.validate_cell(params, mix["loop"])
-    cfg = spec.model_config(cell.config, reduced=reduced)
-    rcfg = spec.reference_config(cfg)
+    family = spec.family(cell.config)
+    cfg = family.model_config(cell.config, reduced)
+    rcfg = family.reference_config(cfg)
     peak = peaks(devices[0].device_kind) if require_tpu else None
 
     from bench import program
 
-    w = weights.make_weights(rcfg, seed)
-    engine = program.build_engine(cfg, w, int(params["n_slots"]), int(params["max_len"]))
+    w = weights.make_weights(family.shapes(rcfg), seed)
+    engine = program.build_engine(family, cfg, w, int(params["n_slots"]),
+                                  int(params["max_len"]))
     if hooks:
         hooks(engine)
     n_warm = _warm_programs(engine, mix, int(params["n_slots"]))
-    traffic = generator.make_traffic(mix, params, seed, seconds, rcfg["vocab_size"])
+    vocab = cfg.vocab
+    traffic = generator.make_traffic(mix, params, seed, seconds, vocab)
     client = Client(engine, traffic, time.perf_counter)
 
     # ---- steady state before the window
@@ -302,7 +305,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t_start: float
     failed = sum(1 for r in due if not r.times)
     ctx = {
         "recs": client.recs, "seconds": float(seconds), "setup_s": setup_s, "loop": traffic.loop,
-        "counters": {k: c1[k] - c0[k] for k in c0}, "cfg": rcfg, "peak": peak,
+        "counters": {k: c1[k] - c0[k] for k in c0}, "cfg": rcfg, "family": family, "peak": peak,
         "trace": None, "traced_steps": None, "log": log,
     }
     red = None
@@ -333,7 +336,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t_start: float
             f"max {max(lateness) * 1e3:.3f} ms")
 
     # ---- the check, once the program's state is freed
-    vocab = rcfg["vocab_size"]
     finished = [(r.planned.prompt, np.asarray(r.tokens, np.int64))
                 for r in client.recs if r.tokens]
     picked = check.sample(finished, seed, int(params["check_tokens"]))
@@ -342,13 +344,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t_start: float
     gc.collect()
     t_check = time.perf_counter()
     outside = sum(int(((s < 0) | (s >= vocab)).sum()) for _, s in finished)
-    gap = (check.widest_gap(w, rcfg, [(p, np.clip(s, 0, vocab - 1)) for p, s in picked])
+    gap = (check.widest_gap(family.logits_at, w, rcfg,
+                            [(p, np.clip(s, 0, vocab - 1)) for p, s in picked])
            if picked else {"value": NOTHING_CHECKED, "tokens": 0, "off_argmax": 0})
     log(f"check: {gap['tokens']} served tokens of {len(picked)} requests, "
         f"{gap['off_argmax']} off the reference argmax, widest gap {gap['value']!r}, "
         f"{time.perf_counter() - t_check:.3f} s")
     if control:
-        ctl = check.widest_gap(w, rcfg, picked, control=True)
+        ctl = check.widest_gap(family.logits_at, w, rcfg, picked, control=True)
         log(f"control: widest gap {ctl['value']!r} over {ctl['tokens']} tokens, "
             f"{ctl['off_argmax']} off the reference argmax (in the program's place)")
     limits = params["limits"]

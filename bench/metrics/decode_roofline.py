@@ -1,11 +1,12 @@
 """decode_roofline: the least time the chip needs for the traced window's
-decode steps (bench/work.py: the larger of FLOPs over peak and bytes over
-HBM bandwidth, each step's bytes counting the live KV of its active rows)
-over the device time of ``jit_decode`` (%)."""
+decode steps (``peaks.least_time`` of the family's ``decode_step``: the
+larger of FLOPs over peak and bytes over HBM bandwidth, each step's bytes
+counting the live KV of its active rows) over the device time of
+``jit_decode`` (%)."""
 
 from collections import Counter
 
-from bench import work
+from bench.peaks import least_time
 
 
 def read(ctx):
@@ -16,7 +17,7 @@ def read(ctx):
     least, bound = 0.0, Counter()
     for _, dec, _ in ctx.traced_steps:
         if dec:
-            t, b = work.least_time(work.decode_step(ctx.cfg, dec), ctx.peak)
+            t, b = least_time(ctx.family.decode_step(ctx.cfg, dec), ctx.peak)
             least += t
             bound[b] += 1
     if not least:

@@ -1,8 +1,7 @@
 """mfu: the model FLOPs the traced window's steps needed (every prefill at
-its true prompt length, every decode row attending its live context;
-bench/work.py) over the traced window times the chip's bf16 peak (%)."""
-
-from bench import work
+its true prompt length, every decode row attending its live context; the
+family's ``decode_step`` and ``prefill``) over the traced window times the
+chip's bf16 peak (%)."""
 
 
 def read(ctx):
@@ -12,7 +11,7 @@ def read(ctx):
     flops = 0.0
     for _, dec, pre in ctx.traced_steps:
         if dec:
-            flops += work.decode_step(ctx.cfg, dec)["flops"]
+            flops += ctx.family.decode_step(ctx.cfg, dec)["flops"]
         for n in pre:
-            flops += work.prefill(ctx.cfg, n)["flops"]
+            flops += ctx.family.prefill(ctx.cfg, n)["flops"]
     return 100.0 * flops / (tr["window_s"] * ctx.peak["bf16_flops"])

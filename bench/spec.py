@@ -2,37 +2,31 @@
 each piece sits in a file of its own under ``bench/``:
 
 * ``bench/cells/<workload>.json``   engine parameters of one cell;
-* ``bench/configs/<config>.json``   one model configuration (published keys);
+* ``bench/configs/<config>.json``   one model configuration (published keys),
+  which names its family under ``"family"``;
+* ``bench/families/<family>.py``    one kind of block: the program's
+  ``ModelConfig`` and the reference's sizes from a configuration file, the
+  weight tree, its place in the program's tree, the plain reference and the
+  algorithm's work counts (``bench/families/gqa.py`` lists them);
 * ``bench/traffic/<traffic>.json``  one traffic mix, read by ``generator.py``;
 * ``bench/metrics/<base>.py``       one metric reader per quantity, where
   ``<base>`` is the metric's name up to its first dot.
 
-A later cell, configuration, mix or metric is a new file and a new entry;
-no file here changes for it.
+A later cell, configuration, family, mix or metric is a new file and a new
+entry; no file here changes for it, a configuration of another kind of
+block included.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
-
-# published config.json keys -> fields of the program's ModelConfig
-CONFIG_FIELDS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_hidden_layers": "n_layers",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "vocab_size": "vocab",
-    "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings",
-}
 
 
 def _load(path: Path) -> dict:
@@ -99,31 +93,32 @@ def load_cell(workload: str, bench: dict | None = None) -> Cell:
     )
 
 
+def family(config: dict):
+    """The module ``bench.families.<config["family"]>``.  There is no default:
+    a configuration that names no family, or one that is not known, is an
+    error that lists the known families."""
+    name = config.get("family")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise ValueError(f"configuration {config.get('name')!r} names no family "
+                         f"(key 'family'); known: {_families()}")
+    module = f"bench.families.{name}"
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise ValueError(f"unknown family {name!r} in configuration "
+                         f"{config.get('name')!r}; known: {_families()}") from None
+
+
+def _families() -> list:
+    return sorted(p.stem for p in (BENCH_DIR / "families").glob("*.py"))
+
+
 def model_config(config: dict, reduced: bool = False):
-    """The program's ``ModelConfig`` for a configuration file: the registry
-    entry with every published key of the file put in, bf16 weights as
-    served.  ``reduced`` takes the registry's CPU-sized preset instead (tests
-    only), with the file's dtypes."""
-    from repro.configs.base import get_config
-
-    base = get_config(config["registry_id"], reduced=reduced)
-    dtype = config["torch_dtype"]
-    if reduced:
-        return dataclasses.replace(base, param_dtype=dtype, compute_dtype=dtype)
-    fields = {f: config[k] for k, f in CONFIG_FIELDS.items()}
-    fields["rope_theta"] = float(fields["rope_theta"])
-    if config.get("sliding_window"):
-        fields.update(attn_type="swa", sliding_window=int(config["sliding_window"]))
-    return dataclasses.replace(base, param_dtype=dtype, compute_dtype=dtype, **fields)
-
-
-def reference_config(cfg) -> dict:
-    """The plain reference's sizes, in the published keys, read back from
-    the ``ModelConfig`` that runs (so reduced test sizes agree too)."""
-    out = {k: getattr(cfg, f) for k, f in CONFIG_FIELDS.items()}
-    out["head_dim"] = cfg.head_dim
-    out["sliding_window"] = cfg.sliding_window if cfg.attn_type == "swa" else 0
-    return out
+    """The program's ``ModelConfig`` for a configuration file, by its
+    family; ``reduced`` takes the registry's CPU-sized preset (tests only)."""
+    return family(config).model_config(config, reduced)
 
 
 def metric_reader(name: str):

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import bisect
 import glob
+import heapq
 import os
 import re
 from collections import defaultdict
@@ -126,12 +127,13 @@ def reduce(profile, top: int = 10) -> dict:
         if i == 0:
             first_merged = merged
     activities = [ev for ev in host if ev[0] != WINDOW]
-    gaps = []
+    idle = []
     edge = lo
     for a, b in first_merged + [[hi, hi]]:
         if a > edge:
-            gaps.append((_attribute(edge, a, activities), (a - edge) * 1e-9))
+            idle.append((edge, a))
         edge = max(edge, b)
+    gaps = [(name, (b - a) * 1e-9) for name, (a, b) in zip(attribute(idle, activities), idle)]
     gaps.sort(key=lambda g: -g[1])
     by_activity = defaultdict(float)
     for name, s in gaps:
@@ -147,13 +149,30 @@ def reduce(profile, top: int = 10) -> dict:
     }
 
 
-def _attribute(a, b, activities) -> str:
-    best, best_overlap, best_len = "host.unannotated", 0.0, float("inf")
-    for name, s, e in activities:
-        overlap = min(b, e) - max(a, s)
-        if overlap <= 0:
-            continue
-        length = e - s
-        if overlap > best_overlap or (overlap == best_overlap and length < best_len):
-            best, best_overlap, best_len = name, overlap, length
-    return best
+def attribute(gaps, activities) -> list[str]:
+    """For each gap ``(a, b)`` of ``gaps`` (in time order, disjoint), the name
+    of the annotation ``(name, start, end)`` of ``activities`` that overlaps
+    it most; at equal overlap the shorter, then the one listed first;
+    ``host.unannotated`` where none overlaps.
+
+    One sweep: the annotations, by start, join a heap by end once they begin
+    before a gap's end, and leave it once they end before a gap's start, so
+    each gap weighs only the annotations that reach into it."""
+    order = sorted(range(len(activities)), key=lambda i: activities[i][1])
+    live, k, out = [], 0, []
+    for a, b in gaps:
+        while k < len(order) and activities[order[k]][1] < b:
+            i = order[k]
+            heapq.heappush(live, (activities[i][2], i))
+            k += 1
+        while live and live[0][0] <= a:
+            heapq.heappop(live)
+        best, best_key = "host.unannotated", None
+        for e, i in live:
+            name, s, _ = activities[i]
+            overlap = min(b, e) - max(a, s)
+            key = (-overlap, e - s, i)
+            if overlap > 0 and (best_key is None or key < best_key):
+                best, best_key = name, key
+        out.append(best)
+    return out

@@ -1,10 +1,12 @@
 """Random weights from ``--seed``, made on the device in one jitted call, in
 the dtype they are served in.
 
-The layout is the reference's (``bench/reference/model.py``): per-layer
-matrices stacked along a leading layer axis, norm weights as published
-(around 1).  ``program.program_params`` hands the same arrays to the
-program in its own tree.
+The tree of shapes is a family's (``shapes`` of ``bench/families/<family>.py``,
+in its reference's layout); a leaf is drawn by the rule its path names:
+a path holding ``norm`` is a norm weight as published (around 1), one
+holding ``embed`` an embedding, any other a matrix scaled by its fan-in
+(the second-last axis).  ``program.program_params`` hands the same arrays
+to the program in its own tree.
 """
 
 from __future__ import annotations
@@ -14,28 +16,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-def shapes(cfg: dict) -> dict:
-    d, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    n, hd = cfg["num_hidden_layers"], cfg["head_dim"]
-    qd, kvd = cfg["num_attention_heads"] * hd, cfg["num_key_value_heads"] * hd
-    return {
-        "embed": (v, d),
-        "final_norm": (d,),
-        "lm_head": (d, v),
-        "layers": {
-            "attn_norm": (n, d),
-            "wq": (n, d, qd),
-            "wk": (n, d, kvd),
-            "wv": (n, d, kvd),
-            "wo": (n, qd, d),
-            "mlp_norm": (n, d),
-            "w_gate": (n, d, f),
-            "w_up": (n, d, f),
-            "w_down": (n, f, d),
-        },
-    }
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -59,8 +39,9 @@ def _make(key, *, treedef, leaves, dtype):
     return jax.tree.unflatten(treedef, out)
 
 
-def make_weights(cfg: dict, seed: int, dtype=jnp.bfloat16) -> dict:
+def make_weights(shapes: dict, seed: int, dtype=jnp.bfloat16) -> dict:
+    """Weights for a tree of shapes (tuples), in ``dtype``."""
     is_shape = lambda s: isinstance(s, tuple)
-    flat, treedef = jax.tree_util.tree_flatten_with_path(shapes(cfg), is_leaf=is_shape)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=is_shape)
     leaves = tuple((jax.tree_util.keystr(p), s) for p, s in flat)
     return _make(seed_key(seed), treedef=treedef, leaves=leaves, dtype=dtype)

@@ -1,8 +1,9 @@
 """The operations and bytes the algorithm needs, from a configuration's
-published sizes and the live lengths of a step, whatever implements it.
+published sizes and the live lengths of a step, whatever implements it:
+the ``gqa`` family's work counts (``bench/families/gqa.py``).
 
-``cfg`` is a dict in the published keys (``spec.reference_config``).  The
-counts are those of a decoder-only GQA transformer with a SwiGLU MLP:
+``cfg`` is a dict in the published keys (the family's ``reference_config``).
+The counts are those of a decoder-only GQA transformer with a SwiGLU MLP:
 
 * weights: every matrix is read once per step, in ``weight_bytes`` per
   element (bf16 as served); the embedding is a gather of one row per token;
@@ -77,11 +78,3 @@ def prefill(cfg: dict, length: int) -> dict:
     body = cfg["num_hidden_layers"] * layer_params(cfg)
     flops = 2 * body * length + 2 * head_params(cfg) + _attn_flops_per_pair(cfg) * attended
     return {"flops": float(flops)}
-
-
-def least_time(work: dict, peak: dict) -> tuple[float, str]:
-    """Roofline: the larger of FLOPs over peak FLOP/s and bytes over HBM
-    bandwidth, and which one bounds."""
-    t_flops = work["flops"] / peak["bf16_flops"]
-    t_bytes = work.get("bytes", 0.0) / peak["hbm_bytes_per_s"]
-    return (t_flops, "flops") if t_flops >= t_bytes else (t_bytes, "bytes")

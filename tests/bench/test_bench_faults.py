@@ -4,7 +4,8 @@ Each test drives a whole run of a cell at the registry's reduced sizes on
 the CPU (the look for a chip skipped), with the engine's compiled programs
 wrapped so that one fault of the kind the cell can have happens where the
 work is done, and sees ``correct`` come out false.  One chip has no
-exchange between chips to leave out.
+exchange between chips to leave out.  A cell run under the test-only
+``gqa_qknorm`` family (``qknorm_family.py``) fails the same way.
 """
 
 import json
@@ -17,16 +18,14 @@ import jax.numpy as jnp
 import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
-sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+sys.path[:0] = [str(ROOT), str(ROOT / "src"), str(Path(__file__).parent)]
 
+import qknorm_family  # noqa: E402
 from bench import harness  # noqa: E402
+from repro.runtime.serving import ROWS  # noqa: E402
 
 with open(Path(__file__).parent / "data" / "rehearsal.json") as _f:
     REHEARSAL = json.load(_f)
-
-
-def _rows_axis(engine):
-    return 1 if engine.pool.stacked else 0
 
 
 def state_unchanged(engine):
@@ -43,7 +42,7 @@ def state_unchanged(engine):
 
 def half_batch_left_out(engine):
     """Decode updates the state of the first half of the slots only."""
-    decode, ax = engine._decode, _rows_axis(engine)
+    decode = engine._decode
 
     def broken(params, caches, *args):
         old = jax.tree.map(jnp.copy, caches)
@@ -52,7 +51,7 @@ def half_batch_left_out(engine):
 
         def keep_half(o, c):
             shape = [1] * c.ndim
-            shape[ax] = n
+            shape[ROWS] = n
             first = (jnp.arange(n) < n // 2).reshape(shape)
             return jnp.where(first, c, o)
 
@@ -85,6 +84,17 @@ FAULTS = [
 @pytest.mark.parametrize("cell,fault", FAULTS, ids=[f"{c}-{f.__name__}" for c, f in FAULTS])
 def test_fault_makes_the_run_incorrect(cell, fault):
     r = harness.run(cell, 21, 2.0, False, t_start=time.perf_counter(), require_tpu=False,
+                    reduced=True, overrides=REHEARSAL[cell], hooks=fault)
+    assert r["correct"] is False
+    gap = r["checks"]["logit_gap"]
+    assert gap["value"] > gap["limit"]
+
+
+@pytest.mark.parametrize("fault", [state_unchanged, token_altered], ids=lambda f: f.__name__)
+def test_fault_makes_an_injected_family_incorrect(fault, monkeypatch):
+    cell = "internlm2-1.8b.chat_open"
+    qknorm_family.install(monkeypatch, cell)
+    r = harness.run(cell, 23, 2.0, False, t_start=time.perf_counter(), require_tpu=False,
                     reduced=True, overrides=REHEARSAL[cell], hooks=fault)
     assert r["correct"] is False
     gap = r["checks"]["logit_gap"]

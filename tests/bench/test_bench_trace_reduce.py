@@ -1,7 +1,10 @@
 """The reduction from a profiler trace to busy time, per-program device time
 and attributed idle gaps, on a hand-built trace whose answers are known."""
 
+import json
+import random
 import sys
+import time
 from pathlib import Path
 from types import SimpleNamespace as NS
 
@@ -75,7 +78,6 @@ def test_a_trace_without_a_window_is_refused():
         trace_reduce.reduce(t)
 
 
-
 def test_busy_time_is_averaged_over_devices():
     t = handmade()
     second = NS(name="/device:TPU:1", lines=[line("XLA Ops", [ev("dot.9", 100, 1000)])])
@@ -119,3 +121,66 @@ def test_a_trace_recorded_on_the_chip():
     assert len(steps) == len(runs) == 5
     lags = [(d - h) * 1e-9 for h, d in zip(steps, runs)]
     assert all(-2e-3 < lag < 2e-3 for lag in lags), lags
+
+
+@pytest.mark.parametrize("name", ["chip_trace", "chip_trace_spans"])
+def test_recorded_traces_reduce_as_before(name):
+    """The whole reduction of each trace recorded on the chip equals what the
+    commit before the one-sweep attribution gave (kept in
+    ``data/trace_reduce_parent.json``)."""
+    data = Path(__file__).parent / "data"
+    with open(data / "trace_reduce_parent.json") as f:
+        want = json.load(f)[name]
+    got = trace_reduce.reduce(trace_reduce.load(str(data / f"{name}.xplane.pb")))
+    assert json.loads(json.dumps(got)) == want
+
+
+def _attribute_loop(a, b, activities):
+    """The rule, one gap at a time over every annotation: the largest
+    overlap, at equal overlap the shorter, then the first listed."""
+    best, best_overlap, best_len = "host.unannotated", 0, float("inf")
+    for name, s, e in activities:
+        overlap = min(b, e) - max(a, s)
+        if overlap <= 0:
+            continue
+        if overlap > best_overlap or (overlap == best_overlap and e - s < best_len):
+            best, best_overlap, best_len = name, overlap, e - s
+    return best
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_one_sweep_gives_the_loops_answer(seed):
+    """Nested, overlapping, touching, empty and equal annotations, and gaps
+    of every length between them."""
+    rng = random.Random(seed)
+    acts = []
+    for i in range(300):
+        s = rng.randrange(0, 2000)
+        e = s + rng.choice([0, 1, 2, 5, rng.randrange(0, 60), rng.randrange(0, 600)])
+        acts.append((f"bench.a{i % 7}", s, e))
+    acts += [("bench.same", 100, 150), ("bench.twin", 100, 150)]  # a tie to the first listed
+    cuts = sorted(rng.sample(range(0, 2100), 160))
+    gaps = [(a, b) for a, b in zip(cuts[::2], cuts[1::2]) if b > a]
+    assert trace_reduce.attribute(gaps, acts) == [_attribute_loop(a, b, acts) for a, b in gaps]
+
+
+def test_a_thousand_gaps_a_step_reduce_in_linear_time():
+    """200 steps of 1,000 device ops with an idle gap after each; the host
+    annotates each step and the hand-back after it.  The loop over every
+    annotation for every gap takes about 15 times as long as the sweep."""
+    step, ops_per_step, pitch, width = 1_000_000, 1000, 900, 500
+    host, ops = [], []
+    for k in range(200):
+        t = k * step
+        host += [ev("bench.step", t, 900_000), ev("bench.record", t + 900_000, 100_000)]
+        ops += [ev(f"fusion.{j}", t + j * pitch, width) for j in range(ops_per_step)]
+    host.append(ev("bench.window", 0, 200 * step))
+    profile = NS(planes=[NS(name="/host:CPU", lines=[line("python", host)]),
+                         NS(name="/device:TPU:0", lines=[line("XLA Ops", ops)])])
+    t0 = time.perf_counter()
+    r = trace_reduce.reduce(profile)
+    assert time.perf_counter() - t0 < 20.0
+    # 999 gaps of 400 ns inside each step; the last, 100,400 ns, is mostly hand-back
+    assert r["idle_by_activity"] == {"bench.step": pytest.approx(200 * 999 * 400e-9),
+                                     "bench.record": pytest.approx(200 * 100_400e-9)}
+    assert r["busy_s"] == pytest.approx(200 * ops_per_step * width * 1e-9)

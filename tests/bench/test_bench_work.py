@@ -10,7 +10,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import work  # noqa: E402
-from bench.peaks import peaks  # noqa: E402
+from bench.peaks import least_time, peaks  # noqa: E402
 
 
 def _cfg(name):
@@ -59,9 +59,9 @@ def test_sliding_window_caps_what_decode_and_prefill_attend():
 
 def test_roofline_picks_the_binding_bound():
     p = peaks("TPU v5 lite")
-    t, bound = work.least_time({"flops": 197e12, "bytes": 819e9 / 2}, p)
+    t, bound = least_time({"flops": 197e12, "bytes": 819e9 / 2}, p)
     assert (t, bound) == (1.0, "flops")
-    t, bound = work.least_time({"flops": 1e12, "bytes": 819e9}, p)
+    t, bound = least_time({"flops": 1e12, "bytes": 819e9}, p)
     assert (t, bound) == (1.0, "bytes")
 
 
