@@ -13,6 +13,7 @@ from typing import Callable, Optional
 
 __all__ = [
     "MoEConfig",
+    "RopeScaling",
     "SSMConfig",
     "MLAConfig",
     "FrontendConfig",
@@ -38,6 +39,18 @@ class MoEConfig:
     # CLEX technique knobs (Sec. 3 of docs/ARCHITECTURE.md)
     hierarchical_a2a: bool = True  # two-stage all-to-all dispatch
     valiant_shuffle: bool = False  # randomized token indirection
+    # DeepSeek-style layers: always-on shared experts (one SwiGLU of width
+    # n_shared_experts * d_expert_ff), top-k weights left unnormalised and
+    # scaled by routed_scaling_factor
+    n_shared_experts: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # expert parallelism's share: the layer holds experts
+    # [held_first, held_first + n_held) of the n_experts it routes over and
+    # computes, with no capacity and nothing dropped, the part of the
+    # result they give (moe.moe_held).  n_held 0: the capacity paths
+    held_first: int = 0
+    n_held: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,11 +66,26 @@ class SSMConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 768
+    q_lora_rank: int = 768  # 0: queries straight from the hidden state (w_q)
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
     v_head_dim: int = 64
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2's modelling code
+    applies it: frequencies blended between interpolated and original over a
+    ramp of dimensions, and the softmax scale multiplied by
+    ``mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,7 +120,11 @@ class ModelConfig:
     n_encoder_layers: int = 0
     frontend: Optional[FrontendConfig] = None
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
     use_rope: bool = True  # Jamba relies on Mamba for position information
+    # leading layers with a dense MLP before the repeated pattern
+    # (DeepSeek's first_k_dense_replace); they run unstacked, once
+    n_dense_layers: int = 0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     param_dtype: str = "float32"
@@ -113,20 +145,26 @@ class ModelConfig:
         return i % self.attn_period == self.attn_offset
 
     def layer_is_moe(self, i: int) -> bool:
-        if self.moe is None:
+        if self.moe is None or i < self.n_dense_layers:
             return False
         return i % self.moe.layer_period == self.moe.layer_offset
 
+    def ffn_width(self, i: int) -> int:
+        """Width of layer ``i``'s MLP: one expert's in an MoE layer."""
+        return self.moe.d_expert_ff if self.layer_is_moe(i) else self.d_ff
+
     def pattern_period(self) -> int:
-        """Smallest period of the (mixer, ffn) layer pattern — scan unit."""
+        """Smallest period of the (mixer, ffn) layer pattern after the
+        leading dense layers — scan unit."""
+        first, n = self.n_dense_layers, self.n_layers - self.n_dense_layers
         period = 1
-        for p in range(1, self.n_layers + 1):
-            if self.n_layers % p:
+        for p in range(1, n + 1):
+            if n % p:
                 continue
             ok = all(
-                self.layer_is_attention(i) == self.layer_is_attention(i % p)
-                and self.layer_is_moe(i) == self.layer_is_moe(i % p)
-                for i in range(self.n_layers)
+                self.layer_is_attention(i) == self.layer_is_attention(first + (i - first) % p)
+                and self.layer_is_moe(i) == self.layer_is_moe(first + (i - first) % p)
+                for i in range(first, self.n_layers)
             )
             if ok:
                 period = p
@@ -153,9 +191,8 @@ class ModelConfig:
             if self.layer_is_attention(li):
                 if self.attn_type == "mla" and self.mla is not None:
                     m = self.mla
-                    total += d * m.q_lora_rank + m.q_lora_rank * self.n_heads * (
-                        m.qk_nope_head_dim + m.qk_rope_head_dim
-                    )
+                    qk = self.n_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+                    total += (d * m.q_lora_rank + m.q_lora_rank * qk) if m.q_lora_rank else d * qk
                     total += d * (m.kv_lora_rank + m.qk_rope_head_dim)
                     total += m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
                     total += self.n_heads * m.v_head_dim * d
@@ -169,7 +206,7 @@ class ModelConfig:
                 moe = self.moe
                 experts = moe.top_k if active_only else moe.n_experts
                 total += d * moe.n_experts  # router
-                total += experts * 3 * d * moe.d_expert_ff
+                total += (experts + moe.n_shared_experts) * 3 * d * moe.d_expert_ff
             elif self.d_ff:
                 total += 3 * d * self.d_ff
         return total
@@ -218,6 +255,7 @@ ARCH_IDS = [
     "seamless-m4t-large-v2",
     "mamba2-1.3b",
     "phi-3-vision-4.2b",
+    "deepseek-v2-lite",
 ]
 
 _MODULES = {
@@ -231,6 +269,7 @@ _MODULES = {
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "mamba2-1.3b": "mamba2_1_3b",
     "phi-3-vision-4.2b": "phi_3_vision_4_2b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

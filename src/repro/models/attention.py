@@ -14,7 +14,12 @@ Three execution paths:
     per row into its layer in place and reads K and V straight out of the
     stack (``layer_slice``), so no layer is copied out or written back.
   * MLA decode uses the absorbed formulation and caches only the latent
-    KV (+ decoupled RoPE keys) — the compression that makes MiniCPM3 cheap.
+    KV and the decoupled RoPE key, side by side in one ``[r, B, L, rank +
+    rope]`` array — the compression that makes MiniCPM3 and DeepSeek-V2
+    cheap.  The layers read the stack without writing it: each attends to
+    its new entry beside the cached ones, and the entries are written after
+    the last layer (``stage_latent`` / ``flush_latent``).  The scores,
+    softmax and output run under the named scope ``mla.attend``.
 
 Caches are dicts of arrays so they stack cleanly under ``lax.scan``.
 """
@@ -28,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .layers import Initializer, apply_rope, dense_init, rms_norm
+from .layers import Initializer, apply_rope, dense_init, rms_norm, rope_attention_scale
 
 __all__ = [
     "attention_init",
@@ -37,6 +42,8 @@ __all__ = [
     "mla_init",
     "mla_apply",
     "init_mla_cache",
+    "stage_latent",
+    "flush_latent",
     "blockwise_attention",
 ]
 
@@ -312,36 +319,78 @@ def mla_init(init: Initializer, cfg: ModelConfig, dtype):
     d = cfg.d_model
     nh = cfg.n_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    params = {
-        "w_dq": dense_init(init, (d, m.q_lora_rank), dtype),
-        "q_norm": jnp.zeros((m.q_lora_rank,), dtype),
-        "w_uq": dense_init(init, (m.q_lora_rank, nh * qk), dtype),
+    if m.q_lora_rank:
+        params = {
+            "w_dq": dense_init(init, (d, m.q_lora_rank), dtype),
+            "q_norm": jnp.zeros((m.q_lora_rank,), dtype),
+            "w_uq": dense_init(init, (m.q_lora_rank, nh * qk), dtype),
+        }
+        axes = {"w_dq": ("embed", None), "q_norm": (None,), "w_uq": (None, "heads")}
+    else:  # no query compression (DeepSeek-V2-Lite)
+        params = {"w_q": dense_init(init, (d, nh * qk), dtype)}
+        axes = {"w_q": ("embed", "heads")}
+    params.update({
         "w_dkv": dense_init(init, (d, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
         "kv_norm": jnp.zeros((m.kv_lora_rank,), dtype),
         "w_uk": dense_init(init, (m.kv_lora_rank, nh * m.qk_nope_head_dim), dtype),
         "w_uv": dense_init(init, (m.kv_lora_rank, nh * m.v_head_dim), dtype),
         "w_o": dense_init(init, (nh * m.v_head_dim, d), dtype),
-    }
-    axes = {
-        "w_dq": ("embed", None),
-        "q_norm": (None,),
-        "w_uq": (None, "heads"),
+    })
+    axes.update({
         "w_dkv": ("embed", None),
         "kv_norm": (None,),
         "w_uk": (None, "heads"),
         "w_uv": (None, "heads"),
         "w_o": ("heads", "embed"),
-    }
+    })
     return params, axes
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=jnp.bfloat16):
+    """The latent cache of one MLA layer: the normalised latent KV and the
+    rotated shared key side by side in one ``[B, L, rank + rope]`` array, so
+    a row's entry is one write."""
     m = cfg.mla
     return {
-        "ckv": jnp.zeros((batch, seq_len, m.kv_lora_rank), dtype),
-        "k_rope": jnp.zeros((batch, seq_len, m.qk_rope_head_dim), dtype),
+        "latent": jnp.zeros((batch, seq_len, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
         "pos": jnp.full((batch, seq_len), -1, jnp.int32),
     }
+
+
+def stage_latent(block_cache: dict) -> dict:
+    """Before a decode pass over a layer-stacked MLA cache: room for each
+    layer's new entry (``"new"`` [r, B, 1, rank + rope]).  The layers read
+    the stack and leave it as it is; ``flush_latent`` writes the entries
+    after the last layer.  Written inside the loop over the layers, one row
+    at a time, the entries make the TPU compiler lay the stack out with the
+    ring axis outside the rows and relay the whole pool on entry and exit;
+    read-only in the loop it keeps the pool's own layout."""
+    mixer = block_cache.get("mixer")
+    if not isinstance(mixer, dict) or "latent" not in mixer:
+        return block_cache
+    lat = mixer["latent"]
+    new = jnp.zeros(lat.shape[:2] + (1,) + lat.shape[3:], lat.dtype)
+    return {**block_cache, "mixer": {**mixer, "new": new}}
+
+
+def flush_latent(block_cache: dict, pos, ragged: bool) -> dict:
+    """After a decode pass: each layer's staged entry into its ring slot of
+    the stack, in place, and its position beside it (``stage_latent``)."""
+    mixer = block_cache.get("mixer")
+    if not isinstance(mixer, dict) or "new" not in mixer:
+        return block_cache
+    lat, cpos, new = mixer["latent"], mixer["pos"], mixer["new"]
+    slot = (pos % lat.shape[2]).astype(jnp.int32)
+    if not ragged:  # lockstep: one shared slot (see attention_apply)
+        slot = slot[0]
+    for layer in range(lat.shape[0]):
+        lat = ring_write(lat, new[layer], layer, slot, axis=1)
+        cpos = _pos_write(cpos, layer, pos, slot)
+    return {**block_cache, "mixer": {"latent": lat, "pos": cpos}}
+
+
+def _mla_rope(x, positions, cfg):
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_scaling)
 
 
 def _mla_qkv(params, x, cfg, positions):
@@ -349,16 +398,18 @@ def _mla_qkv(params, x, cfg, positions):
     compute = x.dtype
     b, s, _ = x.shape
     nh = cfg.n_heads
-    cq = rms_norm(x @ params["w_dq"].astype(compute), params["q_norm"], cfg.norm_eps)
-    q = (cq @ params["w_uq"].astype(compute)).reshape(
-        b, s, nh, m.qk_nope_head_dim + m.qk_rope_head_dim
-    )
+    if m.q_lora_rank:
+        cq = rms_norm(x @ params["w_dq"].astype(compute), params["q_norm"], cfg.norm_eps)
+        q = cq @ params["w_uq"].astype(compute)
+    else:
+        q = x @ params["w_q"].astype(compute)
+    q = q.reshape(b, s, nh, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
 
     dkv = x @ params["w_dkv"].astype(compute)
     ckv = rms_norm(dkv[..., : m.kv_lora_rank], params["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(dkv[..., None, m.kv_lora_rank :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = _mla_rope(dkv[..., None, m.kv_lora_rank :], positions, cfg)[:, :, 0]
     return q_nope, q_rope, ckv, k_rope
 
 
@@ -378,7 +429,10 @@ def mla_apply(
     compute = x.dtype
     b, s, _ = x.shape
     nh = cfg.n_heads
+    rank = m.kv_lora_rank
     scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if cfg.rope_scaling is not None:
+        scale *= rope_attention_scale(cfg.rope_scaling)
     q_nope, q_rope, ckv, k_rope = _mla_qkv(params, x, cfg, positions)
 
     if cache is None:
@@ -390,34 +444,44 @@ def mla_apply(
         out = blockwise_attention(q, k, v, causal=True, window=0, q_offset=0, scale=scale)
         new_cache = None
         if update_cache:
-            new_cache = {"ckv": ckv, "k_rope": k_rope, "pos": positions.astype(jnp.int32)}
+            new_cache = {"latent": jnp.concatenate([ckv, k_rope], axis=-1),
+                         "pos": positions.astype(jnp.int32)}
     else:
-        # absorbed decode: score = q_nope W_uk^T . ckv + q_rope . k_rope
+        # absorbed decode over layer ``layer`` of the stack, which it only
+        # reads: score = [q_nope W_uk^T, q_rope] . [ckv, k_rope] over the
+        # cached entries, the one this step overwrites left out, and over the
+        # new entry itself, staged for ``flush_latent``
         assert s == 1
         pos = positions[:, 0]
-        slot = (pos % cache["ckv"].shape[2]).astype(jnp.int32)
-        if not ragged:  # lockstep: one shared slot (see attention_apply)
-            slot = slot[0]
-        cckv = ring_write(cache["ckv"], ckv, layer, slot, axis=1)
-        ckrope = ring_write(cache["k_rope"], k_rope, layer, slot, axis=1)
-        cpos = _pos_write(cache["pos"], layer, pos, slot)
-        w_uk = params["w_uk"].astype(compute).reshape(m.kv_lora_rank, nh, m.qk_nope_head_dim)
-        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)  # [B,1,H,rank]
-        scores = jnp.einsum(
-            "bshr,blr->bhsl", q_lat, layer_slice(cckv, layer).astype(compute),
-            preferred_element_type=jnp.float32,
-        ) + jnp.einsum(
-            "bshd,bld->bhsl", q_rope, layer_slice(ckrope, layer).astype(compute),
-            preferred_element_type=jnp.float32,
-        )
-        lpos = layer_slice(cpos, layer)
-        valid = (lpos >= 0) & (pos[:, None] >= lpos)
-        scores = jnp.where(valid[:, None, None, :], scores * scale, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        o_lat = jnp.einsum("bhsl,blr->bshr", probs, layer_slice(cckv, layer).astype(jnp.float32))  # [B,1,H,rank]
-        w_uv = params["w_uv"].astype(compute).reshape(m.kv_lora_rank, nh, m.v_head_dim)
-        out = jnp.einsum("bshr,rhd->bshd", o_lat.astype(compute), w_uv)
-        new_cache = {"ckv": cckv, "k_rope": ckrope, "pos": cpos}
+        lat = cache["latent"]
+        new = jnp.concatenate([ckv, k_rope], axis=-1)  # [B, 1, rank + rope]
+        staged = jax.lax.dynamic_update_index_in_dim(
+            cache["new"], new.astype(lat.dtype), layer, 0)
+        own_entry = new[:, 0].astype(lat.dtype).astype(compute)  # as the cache will hold it
+        ring = jnp.arange(lat.shape[2])
+        slot = jnp.broadcast_to(pos % lat.shape[2], pos.shape)
+        lpos = layer_slice(cache["pos"], layer)  # [B, L]
+        valid = (lpos >= 0) & (pos[:, None] >= lpos) & (ring[None] != slot[:, None])
+        w_uk = params["w_uk"].astype(compute).reshape(rank, nh, m.qk_nope_head_dim)
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)  # [B,H,rank]
+        q_full = jnp.concatenate([q_lat, q_rope[:, 0].astype(compute)], axis=-1)
+        with jax.named_scope("mla.attend"):
+            scores = jnp.einsum("bhc,blc->bhl", q_full, layer_slice(lat, layer).astype(compute),
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(valid[:, None, :], scores * scale, NEG_INF)
+            own = jnp.einsum("bhc,bc->bh", q_full, own_entry,
+                             preferred_element_type=jnp.float32)[..., None] * scale
+            top = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), own)
+            e, e_own = jnp.exp(scores - top), jnp.exp(own - top)
+            denom = jnp.sum(e, axis=-1, keepdims=True) + e_own
+            o_lat = jnp.einsum(  # [B,H,rank]
+                "bhl,blr->bhr", (e / denom).astype(compute),
+                layer_slice(lat, layer, 0, rank).astype(compute),
+                preferred_element_type=jnp.float32)
+            o_lat = o_lat + (e_own / denom) * own_entry[:, None, :rank].astype(jnp.float32)
+        w_uv = params["w_uv"].astype(compute).reshape(rank, nh, m.v_head_dim)
+        out = jnp.einsum("bhr,rhd->bhd", o_lat.astype(compute), w_uv)[:, None]
+        new_cache = {**cache, "new": staged}
 
     out = out.reshape(b, s, nh * m.v_head_dim).astype(compute)
     return out @ params["w_o"].astype(compute), new_cache

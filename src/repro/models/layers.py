@@ -8,6 +8,8 @@ map them to mesh axes (see ``repro.runtime.sharding``).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,8 @@ __all__ = [
     "rms_norm",
     "layer_norm",
     "rope_frequencies",
+    "yarn_mscale",
+    "rope_attention_scale",
     "apply_rope",
     "swiglu",
     "mlp_init",
@@ -82,18 +86,59 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float) -> j
     return (out * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
 
 
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
-    """Inverse frequencies for rotary embeddings (half of head_dim)."""
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction for a context ``factor`` times longer."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., seq, heads, head_dim]; positions: [..., seq]."""
+def _yarn_correction_dim(rotations: float, head_dim: int, theta: float, positions: int) -> float:
+    return head_dim * math.log(positions / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+
+def rope_frequencies(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies for rotary embeddings (half of head_dim).  With
+    ``scaling`` (a ``RopeScaling``), YaRN's as DeepSeek-V2's modelling code
+    computes them: interpolated by ``factor`` below the dimensions that turn
+    ``beta_slow`` times over the original length, left as they are above
+    those that turn ``beta_fast`` times, and blended linearly between."""
+    exps = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    if scaling is None:
+        return 1.0 / (theta ** exps)
+    extra = 1.0 / (theta ** exps)
+    inter = 1.0 / (scaling.factor * theta ** exps)
+    n = scaling.original_max_position
+    low = max(math.floor(_yarn_correction_dim(scaling.beta_fast, head_dim, theta, n)), 0)
+    high = min(math.ceil(_yarn_correction_dim(scaling.beta_slow, head_dim, theta, n)),
+               head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp  # where the original frequency is kept
+    return inter * (1 - keep) + extra * keep
+
+
+def rope_attention_scale(scaling) -> float:
+    """The factor YaRN puts on the softmax scale: ``mscale(factor,
+    mscale_all_dim) ** 2`` (1 without scaling)."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, scaling=None) -> jax.Array:
+    """x: [..., seq, heads, head_dim]; positions: [..., seq].  Rotates the
+    pairs (i, i + head_dim/2); ``scaling`` is YaRN's (``rope_frequencies``),
+    whose cos and sin carry ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``."""
     head_dim = x.shape[-1]
-    inv = rope_frequencies(head_dim, theta)
+    inv = rope_frequencies(head_dim, theta, scaling)
     angles = positions[..., :, None].astype(jnp.float32) * inv[None, :]  # [..., seq, hd/2]
     cos = jnp.cos(angles)[..., :, None, :]
     sin = jnp.sin(angles)[..., :, None, :]
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim)
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -51,6 +51,8 @@ class Model:
             )
             params["decoder"] = tf.stack_init(init, cfg, dtype, cross=True)
         else:
+            if cfg.n_dense_layers:
+                params["prelude"] = tf.prelude_init(init, cfg, dtype)
             params["decoder"] = tf.stack_init(init, cfg, dtype)
         return params
 
@@ -68,6 +70,8 @@ class Model:
             axes["encoder"] = tf.stack_axes(cfg, n_layers=cfg.n_encoder_layers, encoder=True)
             axes["decoder"] = tf.stack_axes(cfg, cross=True)
         else:
+            if cfg.n_dense_layers:
+                axes["prelude"] = tf.prelude_axes(cfg)
             axes["decoder"] = tf.stack_axes(cfg)
         return axes
 
@@ -96,6 +100,27 @@ class Model:
             n_layers=cfg.n_encoder_layers, mesh=mesh,
         )
         return rms_norm(x, params["final_norm"], cfg.norm_eps)
+
+    def _decoder(self, params, x, positions, *, caches=None, update_cache=False, impl="xla",
+                 key=None, mesh=None, ragged=False):
+        """The prelude's layers, then the stack's: returns (x, caches, aux),
+        the caches one tuple, the prelude's entries first."""
+        n_pre = self.cfg.n_dense_layers
+        pre, aux = (), None
+        if n_pre:
+            x, pre, aux = tf.prelude_apply(
+                params["prelude"], x, self.cfg, positions=positions,
+                caches=caches[:n_pre] if caches is not None else None,
+                update_cache=update_cache, impl=impl, mesh=mesh, ragged=ragged,
+            )
+        x, new_caches, a = tf.stack_apply(
+            params["decoder"], x, self.cfg, positions=positions,
+            caches=caches[n_pre:] if caches is not None else None, update_cache=update_cache,
+            impl=impl, key=key, mesh=mesh, ragged=ragged,
+        )
+        if new_caches is not None and n_pre:
+            new_caches = tuple(pre) + tuple(new_caches)
+        return x, new_caches, (tf.add_aux(aux, a) if n_pre else a)
 
     def _logits(self, params, x, compute):
         cfg = self.cfg
@@ -138,9 +163,9 @@ class Model:
             x, mask = self._embed_inputs(params, batch, compute)
             b, s, _ = x.shape
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-            x, _, aux = tf.stack_apply(
-                params["decoder"], x, cfg, positions=positions, impl=impl, key=key, mesh=mesh
-            )
+            x, _, aux = self._decoder(params, x, positions, impl=impl, key=key, mesh=mesh)
+        if isinstance(aux, dict):  # a held expert share also counts assignments
+            aux = aux["balance"]
         logits = self._logits(params, x, compute)
         mask = mask * batch.get("mask", jnp.ones_like(mask))
         loss = cross_entropy_loss(logits, batch["targets"], mask)
@@ -151,10 +176,11 @@ class Model:
 
     # ---------------- serving ----------------
     def init_cache(self, batch: int, seq_len: int, mem_len: int = 0):
+        """Decode caches: the prelude layers' entries, then the stack's."""
         cfg = self.cfg
-        return tf.init_stack_cache(
-            cfg, batch, seq_len, cross=cfg.enc_dec, mem_len=mem_len,
-            dtype=jnp.dtype(cfg.compute_dtype),
+        dtype = jnp.dtype(cfg.compute_dtype)
+        return tf.init_prelude_cache(cfg, batch, seq_len, dtype) + tf.init_stack_cache(
+            cfg, batch, seq_len, cross=cfg.enc_dec, mem_len=mem_len, dtype=dtype,
         )
 
     def prefill(self, params, batch, impl: str = "xla", mesh=None, last_pos=None):
@@ -184,10 +210,8 @@ class Model:
             x, _ = self._embed_inputs(params, batch, compute)
             b, s, _ = x.shape
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-            x, new_caches, _ = tf.stack_apply(
-                params["decoder"], x, cfg, positions=positions, update_cache=True, impl=impl,
-                mesh=mesh,
-            )
+            x, new_caches, _ = self._decoder(params, x, positions, update_cache=True, impl=impl,
+                                             mesh=mesh)
         if last_pos is None:
             x_last = x[:, -1:]
         else:
@@ -235,7 +259,7 @@ class Model:
                 cap = min(capacity, cfg.sliding_window)
             if "k" in c:  # GQA: K and V side by side, as the decode cache holds them
                 c = {"kv": jnp.concatenate([c["k"], c["v"]], axis=-1), "pos": c["pos"]}
-            names = ("kv",) if "kv" in c else ("ckv", "k_rope")
+            names = ("kv",) if "kv" in c else ("latent",)
             pos = c["pos"]  # [..., B, L]
             max_pos = jnp.max(pos, axis=-1, keepdims=True)
             keep = (pos >= 0) & (pos > max_pos - cap)
@@ -263,10 +287,13 @@ class Model:
                 out["mixer"] = relay_mixer(out["mixer"])
             return out
 
-        return tf.stack_layers(tuple(relay_block(bc) for bc in caches), cfg.n_layers)
+        n_pre = cfg.n_dense_layers
+        prelude = tuple(jax.tree.map(lambda t: t[None], relay_block(bc)) for bc in caches[:n_pre])
+        return prelude + tf.stack_layers(tuple(relay_block(bc) for bc in caches[n_pre:]),
+                                         cfg.n_layers - n_pre)
 
     def decode_step(self, params, caches, tokens, pos, impl: str = "xla", mesh=None,
-                    ragged: bool = False):
+                    ragged: bool = False, with_aux: bool = False):
         """One token per sequence.  tokens [B, 1]; pos [B] absolute position.
 
         ``ragged=False`` (seed behaviour) assumes the batch advances in
@@ -274,18 +301,17 @@ class Model:
         the continuous-batching contract: each row is an independent request
         at its own position, writing its own (slot-indexed) cache row.
 
-        Returns (logits [B, 1, V], new_caches).
+        Returns (logits [B, 1, V], new_caches), and with ``with_aux`` the
+        blocks' summed auxiliary outputs third (``transformer.zero_aux``).
         """
         cfg = self.cfg
         compute = jnp.dtype(cfg.compute_dtype)
         x = params["embed"].astype(compute)[tokens]
         positions = pos[:, None]
-        x, new_caches, _ = tf.stack_apply(
-            params["decoder"], x, cfg, positions=positions, caches=caches, impl=impl, mesh=mesh,
-            ragged=ragged,
-        )
+        x, new_caches, aux = self._decoder(params, x, positions, caches=caches, impl=impl,
+                                           mesh=mesh, ragged=ragged)
         logits = self._logits(params, x, compute)
-        return logits, new_caches
+        return (logits, new_caches, aux) if with_aux else (logits, new_caches)
 
 
 def build_model(cfg: ModelConfig) -> Model:

@@ -17,6 +17,13 @@ Paths:
 The routing math is identical in both paths; tests assert exact agreement.
 Per-expert matmuls use a grouped einsum whose Pallas counterpart is
 ``repro.kernels.moe_gmm``.
+
+A config that names a held share (``MoEConfig.n_held``) takes neither:
+``moe_held`` routes over every expert in float32, computes only the
+assignments that land on the experts the layer holds, with no capacity and
+nothing dropped, and adds the shared experts.  The share is the config's,
+not the mesh's: on one chip it stands for one rank of expert parallelism,
+whose partial sum goes on to the next layer.
 """
 
 from __future__ import annotations
@@ -25,19 +32,26 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels.moe_gmm import ops as gmm_ops
 from ..launch import jax_compat
-from .layers import Initializer, dense_init, swiglu
+from .layers import Initializer, dense_init, mlp_apply, mlp_init, swiglu
 
-__all__ = ["moe_init", "moe_apply", "router_topk", "moe_local"]
+__all__ = ["moe_init", "moe_apply", "router_topk", "moe_local", "moe_held", "HELD_WEIGHTS"]
+
+# the held share's expert weights: the decode loop hands moe_held their
+# whole layer stack, which its kernel reads in place (moe_held's docstring)
+HELD_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 def moe_init(init: Initializer, cfg: ModelConfig, dtype):
+    """Router over all ``n_experts``; expert weights for the held share
+    (``n_held``, else every expert); shared experts as one SwiGLU."""
     moe = cfg.moe
     d = cfg.d_model
     f = moe.d_expert_ff
-    e = moe.n_experts
+    e = moe.n_held or moe.n_experts
     params = {
-        "router": dense_init(init, (d, e), dtype, scale=0.02),
+        "router": dense_init(init, (d, moe.n_experts), dtype, scale=0.02),
         "w_gate": dense_init(init, (e, d, f), dtype),
         "w_up": dense_init(init, (e, d, f), dtype),
         "w_down": dense_init(init, (e, f, d), dtype),
@@ -48,6 +62,8 @@ def moe_init(init: Initializer, cfg: ModelConfig, dtype):
         "w_up": ("experts", "embed", "ff"),
         "w_down": ("experts", "ff", "embed"),
     }
+    if moe.n_shared_experts:
+        params["shared"], axes["shared"] = mlp_init(init, d, moe.n_shared_experts * f, dtype)
     return params, axes
 
 
@@ -114,8 +130,6 @@ def moe_local(params, x_flat, cfg: ModelConfig, *, impl: str = "xla"):
         jnp.where(keep[:, None], x_flat[token_of], 0.0)
     )
     if impl == "pallas":
-        from ..kernels.moe_gmm import ops as gmm_ops
-
         out_buckets = gmm_ops.expert_ffn(params, buckets)
     else:
         out_buckets = _expert_ffn(params, buckets, compute)
@@ -205,15 +219,97 @@ def moe_replicated_ep(params, x_flat, cfg: ModelConfig, *, model_axis: str = "mo
     return jax.lax.psum(partial, model_axis), aux[None]
 
 
-def moe_apply(params, x, cfg: ModelConfig, *, impl: str = "xla", key=None, mesh=None):
-    """[B, S, D] -> ([B, S, D], aux).  Chooses the execution path from the
-    mesh threaded in by the caller (explicit Mesh/MeshContext; ambient
-    ``use_mesh`` as fallback): token-sharded a2a EP when enough tokens,
-    replicated EP for tiny (decode) token counts, single-device reference
-    otherwise."""
+def route_dropless(router_w, x_flat, moe):
+    """DeepSeek's gate: logits over all experts in float32, softmax, top-k;
+    the k weights renormalised (``norm_topk_prob``) or scaled by
+    ``routed_scaling_factor``.  Returns (weights [T,k] f32, experts [T,k],
+    Switch aux loss)."""
+    logits = jnp.dot(x_flat.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, moe.top_k)
+    if moe.top_k > 1 and moe.norm_topk_prob:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    else:
+        weights = weights * moe.routed_scaling_factor
+    onehot = jax.nn.one_hot(experts[:, 0], moe.n_experts, dtype=jnp.float32)
+    aux = moe.n_experts * jnp.mean(onehot.mean(0) * probs.mean(0))
+    return weights, experts, aux
+
+
+def moe_held(params, x_flat, cfg: ModelConfig, layer=None):
+    """The held share's part of a dropless MoE layer, plus the shared
+    experts.  x_flat [T, D] -> ([T, D], aux loss, held assignments, held
+    experts with at least one assignment).
+
+    In the decode loop the expert weights arrive as the whole layer stack
+    (``[r, held, ...]``) with the loop's ``layer``.  The grouped product is a
+    kernel that reads its weights group by group, so it is handed the stack
+    as ``r * held`` groups of which only the layer's have rows, and reads the
+    layer's experts where they lie: given the layer's slice, the kernel's
+    operand would be a copy of every held weight, every layer, every step.
+
+    Every (token, expert) assignment is sorted by the held expert it lands
+    on, those on no held expert last; one grouped product a weight
+    (``kernels.moe_gmm.ops.ragged_gmm``, group sizes from the sort)
+    computes the held rows and nothing else, and each token sums its own k
+    rows in a fixed order, so a row's output does not depend on what it is
+    batched with.  The kernel reads an expert's weights only where its
+    group has rows, hence the count of experts touched."""
+    moe = cfg.moe
+    compute = x_flat.dtype
+    t, d = x_flat.shape
+    k, held = moe.top_k, moe.n_held
+    with jax.named_scope("moe.route"):
+        weights, experts, aux = route_dropless(params["router"], x_flat, moe)
+    with jax.named_scope("moe.held"):
+        local = experts.reshape(-1) - moe.held_first  # [T*k]
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)  # not held: past the last group
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+        rows = x_flat[order // k]
+        w_gate, w_up, w_down = (params[name] for name in HELD_WEIGHTS)
+        groups = sizes
+        if w_gate.ndim == 4:  # [r, held, ...]: the decode loop's stack
+            r = w_gate.shape[0]
+            w_gate, w_up, w_down = (w.reshape((r * held,) + w.shape[2:])
+                                    for w in (w_gate, w_up, w_down))
+            groups = jax.lax.dynamic_update_slice(jnp.zeros(r * held, jnp.int32), sizes,
+                                                  (layer * held,))
+
+        def grouped(a, w):
+            return gmm_ops.ragged_gmm(a, w.astype(compute), groups)
+
+        h = swiglu(grouped(rows, w_gate), grouped(rows, w_up))
+        y = grouped(h, w_down)
+        # rows past the held groups are never computed: select, not scale
+        y = jnp.where(mine[order][:, None], y.astype(jnp.float32), 0.0)
+        y = jnp.zeros_like(y).at[order].set(y, unique_indices=True)
+        y = (y.reshape(t, k, d) * weights[..., None]).sum(axis=1)
+    out = y.astype(compute)
+    if moe.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            out = out + mlp_apply(params["shared"], x_flat, compute)
+    return out, aux, mine.sum(dtype=jnp.int32), (sizes > 0).sum(dtype=jnp.int32)
+
+
+def moe_apply(params, x, cfg: ModelConfig, *, impl: str = "xla", key=None, mesh=None,
+              layer=None):
+    """[B, S, D] -> ([B, S, D], aux).  A config with a held expert share
+    runs ``moe_held`` whatever the mesh (``layer``: see there), and its aux
+    is ``{"balance": loss, "held": assignments computed, "held_experts":
+    held experts given rows}``.  Otherwise the
+    path follows the mesh threaded in by the caller (explicit
+    Mesh/MeshContext; ambient ``use_mesh`` as fallback): token-sharded a2a
+    EP when enough tokens, replicated EP for tiny (decode) token counts,
+    single-device reference otherwise."""
     P = jax.sharding.PartitionSpec
     b, s, d = x.shape
     x_flat = x.reshape(b * s, d)
+    if cfg.moe.n_held:
+        out, aux, held, touched = moe_held(params, x_flat, cfg, layer)
+        return out.reshape(b, s, d), {"balance": aux, "held": held, "held_experts": touched}
     mesh = jax_compat.resolve_mesh(mesh)
     if mesh is None or "model" not in mesh.axis_names or mesh.model_size() == 1:
         out, aux = moe_local(params, x_flat, cfg, impl=impl)

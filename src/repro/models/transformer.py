@@ -2,12 +2,17 @@
 
 A model is a repeated *pattern* of blocks (the smallest period of the
 (mixer, ffn) layer spec — 1 for uniform models, 8 for Jamba's 1:7
-Mamba/attention interleave).  Training and prefill scan over pattern
+Mamba/attention interleave), after an optional *prelude* of leading dense
+layers (``cfg.n_dense_layers``, DeepSeek's first dense layer) that run
+once each, unstacked.  Training and prefill scan over pattern
 repeats (`lax.scan`) so compile time and HLO size are O(pattern), with
 optional rematerialisation per repeat.  Decode caches are layer-stacked
 too (leaves ``[r, B, ...]``), and decode loops over the repeats with the
 whole stack in the loop's carry: each layer writes its new entries into the
-stack in place and attention reads its layer straight out of it.
+stack in place (an MLA layer stages its entry, written after the last
+layer: ``attention.stage_latent``) and attention reads its layer straight
+out of it.  A prelude layer's decode cache carries a leading layer axis of
+1, so every decode-cache leaf has its rows on axis 1.
 
 Block = norm -> mixer (attention | MLA | SSM) [+ cross-attention for
 decoders] -> residual -> norm -> FFN (dense SwiGLU | MoE) -> residual.
@@ -30,7 +35,7 @@ from .layers import Initializer, mlp_apply, mlp_init, rms_norm
 
 __all__ = [
     "block_init", "block_apply", "stack_init", "stack_apply", "init_stack_cache",
-    "stack_layers",
+    "stack_layers", "prelude_init", "prelude_axes", "prelude_apply", "init_prelude_cache",
 ]
 
 
@@ -67,6 +72,21 @@ def _mixer_kind(cfg: ModelConfig, j: int, encoder: bool) -> str:
     return "ssm"
 
 
+def zero_aux(cfg: ModelConfig):
+    """A block's auxiliary outputs, zero: the MoE balance loss, and for a
+    held expert share also the (token, expert) assignments it computed and
+    the held experts it gave rows (``moe.moe_held``)."""
+    balance = jnp.zeros((), jnp.float32)
+    if cfg.moe is not None and cfg.moe.n_held:
+        zero = jnp.zeros((), jnp.int32)
+        return {"balance": balance, "held": zero, "held_experts": zero}
+    return balance
+
+
+def add_aux(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
 def block_init(init: Initializer, cfg: ModelConfig, j: int, dtype, *, encoder=False, cross=False):
     d = cfg.d_model
     kind = _mixer_kind(cfg, j, encoder)
@@ -89,7 +109,7 @@ def block_init(init: Initializer, cfg: ModelConfig, j: int, dtype, *, encoder=Fa
     elif cfg.d_ff:
         params["ln2"] = jnp.zeros((d,), dtype)
         axes["ln2"] = ("embed",)
-        params["ffn"], axes["ffn"] = mlp_init(init, cfg.d_model, cfg.d_ff, dtype)
+        params["ffn"], axes["ffn"] = mlp_init(init, cfg.d_model, cfg.ffn_width(j), dtype)
     return params, axes
 
 
@@ -201,11 +221,12 @@ def block_apply(
             memory_kv = jax.tree.map(lambda t: attn_mod.layer_slice(t, layer), memory_kv)
         x = x + _cross_attention(params["cross"], hc, memory_kv, cfg, x.dtype)
 
-    aux = jnp.zeros((), jnp.float32)
+    aux = zero_aux(cfg)
     if "ffn" in params:
         h2 = rms_norm(x, params["ln2"], cfg.norm_eps)
         if cfg.layer_is_moe(j) and not encoder:
-            out2, aux = moe_mod.moe_apply(params["ffn"], h2, cfg, impl=impl, key=key, mesh=mesh)
+            out2, aux = moe_mod.moe_apply(params["ffn"], h2, cfg, impl=impl, key=key, mesh=mesh,
+                                          layer=layer)
         else:
             out2 = mlp_apply(params["ffn"], h2, x.dtype, mesh=mesh)
         x = x + out2
@@ -233,18 +254,22 @@ def _enc_qkv(params, h, cfg):
 # --------------------------------------------------------------------------
 
 
-def _stack_period(cfg: ModelConfig, n_layers: int, encoder: bool) -> int:
+def _stack_range(cfg: ModelConfig, n_layers, encoder: bool) -> tuple[int, int, int]:
+    """(first, n, p): the stack's first layer index, its layer count (after
+    a decoder's prelude) and the period its blocks repeat with."""
+    first = 0 if encoder else cfg.n_dense_layers
+    n = n_layers or cfg.n_layers - first
     p = 1 if encoder else cfg.pattern_period()
-    return p if n_layers % p == 0 else 1
+    return first, n, (p if n % p == 0 else 1)
 
 
 def stack_init(init: Initializer, cfg: ModelConfig, dtype, *, n_layers=None, encoder=False,
                cross=False):
-    n_layers = n_layers or cfg.n_layers
-    p = _stack_period(cfg, n_layers, encoder)
+    first, n_layers, p = _stack_range(cfg, n_layers, encoder)
     r = n_layers // p
     rows = [
-        [block_init(init, cfg, j, dtype, encoder=encoder, cross=cross)[0] for j in range(p)]
+        [block_init(init, cfg, first + j, dtype, encoder=encoder, cross=cross)[0]
+         for j in range(p)]
         for _ in range(r)
     ]
     pattern = []
@@ -259,13 +284,12 @@ def stack_init(init: Initializer, cfg: ModelConfig, dtype, *, n_layers=None, enc
 
 def stack_axes(cfg: ModelConfig, *, n_layers=None, encoder=False, cross=False):
     """Logical axis names per param leaf; scanned leaves get 'layers' first."""
-    n_layers = n_layers or cfg.n_layers
-    p = _stack_period(cfg, n_layers, encoder)
+    first, n_layers, p = _stack_range(cfg, n_layers, encoder)
     r = n_layers // p
     dummy = Initializer(jax.random.PRNGKey(0), abstract=True)
     pattern_axes = []
     for j in range(p):
-        _, aj = block_init(dummy, cfg, j, jnp.float32, encoder=encoder, cross=cross)
+        _, aj = block_init(dummy, cfg, first + j, jnp.float32, encoder=encoder, cross=cross)
         if r > 1:
             aj = jax.tree.map(
                 lambda t: ("layers",) + tuple(t), aj, is_leaf=lambda t: isinstance(t, tuple)
@@ -279,15 +303,14 @@ def init_stack_cache(cfg: ModelConfig, batch: int, seq_len: int, *, n_layers=Non
     """Decode caches, one entry per pattern position, every leaf stacked over
     the pattern's ``r`` repeats (``[r, B, ...]``, ``r`` >= 1): the carry of
     the decode loop in ``stack_apply``, donated and updated in place."""
-    n_layers = n_layers or cfg.n_layers
-    p = _stack_period(cfg, n_layers, False)
+    first, n_layers, p = _stack_range(cfg, n_layers, False)
     r = n_layers // p
 
     def stack(t):
         return jnp.broadcast_to(t, (r,) + t.shape)
 
     return tuple(
-        jax.tree.map(stack, init_block_cache(cfg, j, batch, seq_len, cross=cross,
+        jax.tree.map(stack, init_block_cache(cfg, first + j, batch, seq_len, cross=cross,
                                              mem_len=mem_len, dtype=dtype))
         for j in range(p)
     )
@@ -296,10 +319,61 @@ def init_stack_cache(cfg: ModelConfig, batch: int, seq_len: int, *, n_layers=Non
 def stack_layers(caches: tuple, n_layers: int) -> tuple:
     """Caches as a scanned prefill emits them (one entry per pattern
     position, leaves ``[r, B, ...]`` only where the pattern repeats ``r > 1``
-    times) with the leading repeat axis that decode caches always carry."""
+    times) with the leading repeat axis that decode caches always carry.
+    ``n_layers`` counts the stack's layers."""
     if n_layers // len(caches) > 1:
         return tuple(caches)
     return tuple(jax.tree.map(lambda t: t[None], c) for c in caches)
+
+
+# --------------------------------------------------------------------------
+# prelude: leading dense layers, each its own block, before the stack
+# --------------------------------------------------------------------------
+
+
+def prelude_init(init: Initializer, cfg: ModelConfig, dtype) -> tuple:
+    return tuple(block_init(init, cfg, i, dtype)[0] for i in range(cfg.n_dense_layers))
+
+
+def prelude_axes(cfg: ModelConfig) -> tuple:
+    dummy = Initializer(jax.random.PRNGKey(0), abstract=True)
+    return tuple(block_init(dummy, cfg, i, jnp.float32)[1] for i in range(cfg.n_dense_layers))
+
+
+def init_prelude_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=jnp.bfloat16) -> tuple:
+    """One decode cache per prelude layer, each leaf with a leading layer
+    axis of 1 (``[1, B, ...]``), the layout of the stack's."""
+    return tuple(jax.tree.map(lambda t: t[None], init_block_cache(cfg, i, batch, seq_len,
+                                                                   dtype=dtype))
+                 for i in range(cfg.n_dense_layers))
+
+
+def prelude_apply(params: tuple, x, cfg: ModelConfig, *, positions, caches=None,
+                  update_cache=False, impl="xla", mesh=None, ragged=False):
+    """Returns (x, new_caches, aux) after the prelude layers.  Decode
+    ``caches`` are one-deep stacks, read and written at layer 0."""
+    decode = caches is not None
+    aux, new = zero_aux(cfg), []
+    for i, lp in enumerate(params):
+        cache = attn_mod.stage_latent(caches[i]) if decode else None
+        x, nc, a = block_apply(
+            lp, x, cfg, i, positions=positions, cache=cache, update_cache=update_cache,
+            impl=impl, mesh=mesh, ragged=ragged, layer=0 if decode else None,
+        )
+        aux = add_aux(aux, a)
+        new.append(attn_mod.flush_latent(nc, positions[:, 0], ragged) if decode else nc)
+    return x, tuple(new), aux
+
+
+def _layer_params(block_params: dict, rep, cfg: ModelConfig) -> dict:
+    """Repeat ``rep`` of a pattern position's stacked params; a held expert
+    share's weights stay stacked for ``moe.moe_held``, whose grouped kernel
+    reads the layer's experts out of the stack in place."""
+    out = jax.tree.map(lambda t: t[rep], block_params)
+    ffn = block_params.get("ffn", {})
+    if cfg.moe is not None and cfg.moe.n_held and "router" in ffn:
+        out["ffn"] = {**out["ffn"], **{k: ffn[k] for k in moe_mod.HELD_WEIGHTS}}
+    return out
 
 
 def stack_apply(
@@ -318,7 +392,7 @@ def stack_apply(
     ragged: bool = False,
 ):
     """Returns (x, new_caches, aux_total)."""
-    n_layers = n_layers or cfg.n_layers
+    first, n_layers, _ = _stack_range(cfg, n_layers, encoder)
     p = len(pattern_params)
     r = n_layers // p
 
@@ -335,19 +409,20 @@ def stack_apply(
             for j in range(p):
                 lp = pattern_params[j]
                 if r > 1:
-                    lp = jax.tree.map(lambda t: t[rep], lp)
+                    lp = _layer_params(lp, rep, cfg)
                 h, nc, a = block_apply(
-                    lp, h, cfg, j, positions=positions, cache=stacks[j], encoder=encoder,
-                    impl=impl, key=key, mesh=mesh, ragged=ragged, layer=rep,
+                    lp, h, cfg, first + j, positions=positions, cache=stacks[j],
+                    encoder=encoder, impl=impl, key=key, mesh=mesh, ragged=ragged, layer=rep,
                 )
-                aux = aux + a
+                aux = add_aux(aux, a)
                 new.append(nc)
             return h, aux, tuple(new)
 
-        carry = (x, jnp.zeros((), jnp.float32), tuple(caches))
+        carry = (x, zero_aux(cfg), tuple(attn_mod.stage_latent(c) for c in caches))
         x, aux, new_caches = (layer(0, carry) if r == 1
                               else jax.lax.fori_loop(0, r, layer, carry))
-        return x, new_caches, aux
+        pos = positions[:, 0]
+        return x, tuple(attn_mod.flush_latent(c, pos, ragged) for c in new_caches), aux
 
     def body(carry, xs):
         h, aux = carry
@@ -357,11 +432,11 @@ def stack_apply(
         for j in range(p):
             cache_j = layer_caches[j] if layer_caches is not None else None
             h, nc, a = block_apply(
-                layer_params[j], h, cfg, j, positions=positions, cache=cache_j,
+                layer_params[j], h, cfg, first + j, positions=positions, cache=cache_j,
                 update_cache=update_cache, encoder=encoder, impl=impl, key=key, mesh=mesh,
                 ragged=ragged,
             )
-            aux = aux + a
+            aux = add_aux(aux, a)
             new_caches.append(nc if nc is not None else {})
         h = constrain_residual(h, cfg, mesh)
         return (h, aux), tuple(new_caches)
@@ -372,13 +447,13 @@ def stack_apply(
 
     if r == 1:
         (x, aux), emit = fn(
-            (x, jnp.zeros((), jnp.float32)),
+            (x, zero_aux(cfg)),
             (pattern_params, caches),
         )
         new_caches = emit if (caches is not None or update_cache) else None
         return x, new_caches, aux
 
     xs = (pattern_params, caches if caches is not None else tuple({} for _ in range(p)))
-    (x, aux), emitted = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)), xs)
+    (x, aux), emitted = jax.lax.scan(fn, (x, zero_aux(cfg)), xs)
     new_caches = emitted if (caches is not None or update_cache) else None
     return x, new_caches, aux

@@ -20,7 +20,9 @@ timeline: entering it enters a ``jax.profiler.TraceAnnotation`` named
 was doing between the device's programs.  The annotation carries the name
 only (attributes stay in the tracer's event), so the name is a stable key
 for whoever reads the profile.  :func:`lane` gives a host whose own tracing
-is off the same annotation, and only while a profiler session records.
+is off the same annotation, and only while a profiler session records, and
+may add stats to it: the names stay the same (``serve.emit`` carries a
+step's held-expert counts that way).
 
 Zero-cost discipline: the disabled :class:`~repro.obs.Obs` bundle
 returns the preallocated :data:`NULL_SPAN` singleton (whose
@@ -77,13 +79,16 @@ def _profiler_annotation():
     return _annotation
 
 
-def lane(name: str):
+def lane(name: str, stats: dict | None = None):
     """``name`` on the profiler's timeline alone, for a host whose tracer is
     off: a ``TraceAnnotation`` while a profiler session records, else
     :data:`NULL_SPAN` (no allocation).  ``name`` is the ``"<cat>.<name>"``
-    an enabled tracer's span would carry."""
+    an enabled tracer's span would carry, ``stats`` the attributes it would
+    open with, which the profile keeps as the event's stats."""
     annotation = _profiler_annotation()
-    return annotation(name) if annotation.is_enabled() else NULL_SPAN
+    if not annotation.is_enabled():
+        return NULL_SPAN
+    return annotation(name, **stats) if stats else annotation(name)
 
 
 class Span:

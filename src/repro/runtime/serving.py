@@ -817,6 +817,13 @@ class EngineMetrics:
         # backend compiles JAX reported during the engine's prefill/decode
         # calls: each is a step that paid for a new program
         ("compiles", 0),
+        # (token, expert) assignments the decode steps computed on the
+        # layers' held experts, every row of the pool counted, and the
+        # (layer, held expert) pairs given at least one of them, whose
+        # weights the steps read (models with a held expert share only:
+        # MoEConfig.n_held)
+        ("moe_held_assignments", 0),
+        ("moe_held_experts", 0),
     )
 
     def __init__(self, registry: MetricsRegistry | None = None):
@@ -938,6 +945,9 @@ class ContinuousBatchingEngine:
             )
         self.scheduler = scheduler
 
+        # a held expert share counts its decode assignments and experts
+        # (EngineMetrics)
+        self._counts_held = cfg.moe is not None and bool(cfg.moe.n_held)
         # SSM state has no positional record, so right-padded prefill would
         # advance it through pad tokens — bucket only pure-attention stacks
         self._bucket_prompts = all(cfg.layer_is_attention(i) for i in range(cfg.n_layers))
@@ -996,6 +1006,7 @@ class ContinuousBatchingEngine:
         m = self.model
         seed = self.seed
         max_len = self.pool.capacity
+        counts_held = self._counts_held
 
         # sampling is deterministic per (seed, request id, token index): the
         # drawn token never depends on slot assignment or admission order
@@ -1030,10 +1041,15 @@ class ContinuousBatchingEngine:
 
         @partial(jax.jit, donate_argnums=(1,))
         def decode(params, pool_caches, tokens, pos, temps, rids, idxs):
-            logits, pool_caches = m.decode_step(
-                params, pool_caches, tokens[:, None], pos, mesh=mesh_, ragged=True
+            """Next token of every row; a model with a held expert share
+            appends the step's held assignments and held experts given
+            rows, so the counts ride the tokens' copy to the host."""
+            logits, pool_caches, aux = m.decode_step(
+                params, pool_caches, tokens[:, None], pos, mesh=mesh_, ragged=True, with_aux=True
             )
             toks = jax.vmap(sample_one)(logits[:, 0], temps, rids, idxs)
+            if counts_held:
+                toks = jnp.concatenate([toks, jnp.stack([aux["held"], aux["held_experts"]])])
             return toks, pool_caches
 
         self._prefill_into = prefill_into
@@ -1491,9 +1507,16 @@ class ContinuousBatchingEngine:
             with obs.tracer.span("sync", "serve") if obs.enabled else lane("serve.sync"):
                 toks = np.asarray(toks)
             t_tok = self._stamp()
+        held = None
+        if self._counts_held:
+            # the step's counts also ride its emit span onto the profile
+            held = {"moe_held_assignments": int(toks[-2]), "moe_held_experts": int(toks[-1])}
+            self.metrics.moe_held_assignments += held["moe_held_assignments"]
+            self.metrics.moe_held_experts += held["moe_held_experts"]
         self.metrics.decode_steps += 1
         self.metrics.total_slot_steps += self.pool.n_slots
-        with obs.tracer.span("emit", "serve") if obs.enabled else lane("serve.emit"):
+        with (obs.tracer.span("emit", "serve", **(held or {})) if obs.enabled
+              else lane("serve.emit", held)):
             for slot, req in enumerate(self._slot_req):
                 if req is None:
                     continue

@@ -134,7 +134,7 @@ def cache_shardings(cache_tree, mesh):
                     entries[kvdim] = "model"
                 elif arr.shape[sdim] % mp == 0:
                     entries[sdim] = "model"  # split-KV decode
-            elif name in ("ckv", "k_rope"):
+            elif name == "latent":  # MLA [r, B, L, rank + rope]: split the sequence
                 if arr.shape[2] % mp == 0:
                     entries[2] = "model"
             elif name == "conv":

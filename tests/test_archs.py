@@ -109,5 +109,6 @@ def test_full_config_parameter_scale(arch):
         "seamless-m4t-large-v2": 2.3e9,
         "mamba2-1.3b": 1.3e9,
         "phi-3-vision-4.2b": 3.8e9,
+        "deepseek-v2-lite": 15.7e9,
     }[arch]
     assert 0.5 * nominal < n < 1.7 * nominal, f"{arch}: {n/1e9:.2f}B vs nominal {nominal/1e9:.1f}B"

@@ -101,6 +101,8 @@ WRITE_CASES = {
     "gqa_one_layer": ("internlm2-1.8b", {"n_layers": 1}, True),
     "swa_ring_wrap": ("h2o-danube-1.8b", {"sliding_window": 8}, True),
     "mla": ("minicpm3-4b", {}, True),
+    # a dense prelude layer's latent cache, then two stacked MoE layers'
+    "mla_prelude": ("deepseek-v2-lite", {"n_layers": 3}, True),
     "gqa_lockstep": ("internlm2-1.8b", {}, False),
 }
 
@@ -400,10 +402,48 @@ def test_moe_engine_runs_and_prices_admission(tiny_moe):
     assert [len(o) for o in out] == [3, 3, 3]
     assert eng.metrics.predicted_a2a_s > 0  # cost model actually consulted
     # fixed configuration is reproducible (slot-count invariance does not
-    # hold for MoE: expert capacity couples co-batched rows)
+    # hold for the capacity paths: expert capacity couples co-batched rows;
+    # the dropless held-share path has no such coupling, next test)
     eng2 = ContinuousBatchingEngine(model, params, n_slots=2, max_len=32,
                                     policy="cost_aware", seed=0)
     for a, b in zip(out, eng2.generate(prompts, [3, 3, 3])):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_dropless_moe_rows_do_not_depend_on_their_batch_or_pool():
+    """With a held expert share (no capacity, nothing dropped) a row's decode
+    logits are the same bits whatever rows share its pool; across pool sizes
+    XLA:CPU's matmuls choose kernels by row count, so a dense layer already
+    moves the last bits there (1e-6), and the served tokens agree."""
+    cfg = get_config("deepseek-v2-lite", reduced=True)
+    cfg = dataclasses.replace(cfg, compute_dtype="float32", remat=False,
+                              moe=dataclasses.replace(cfg.moe, held_first=4, n_held=4))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(1))
+    rng = np.random.default_rng(0)
+    prompts = _prompts(rng, cfg.vocab, [9, 17, 5, 12])
+    rows = []
+    for p in prompts:
+        _, c = model.prefill(params, {"tokens": jnp.asarray(p[None])})
+        rows.append(model.prepare_decode_caches(c, capacity=32))
+    toks = jnp.asarray(rng.integers(1, cfg.vocab, (4, 1)), jnp.int32)
+    pos = jnp.asarray([len(p) for p in prompts], jnp.int32)
+    step = jax.jit(lambda c, t, q: model.decode_step(params, c, t, q, ragged=True)[0])
+    pool = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1), *rows)
+    logits = np.asarray(step(pool, toks, pos))[:, 0]
+    flipped = np.asarray(step(jax.tree.map(lambda a: a[:, ::-1], pool), toks[::-1], pos[::-1]))
+    np.testing.assert_array_equal(logits, flipped[::-1, 0])
+    alone = jax.tree.map(lambda a: jnp.concatenate([a[:, :1], jnp.zeros_like(a[:, 1:])], 1), pool)
+    np.testing.assert_array_equal(logits[0], np.asarray(step(alone, toks, pos))[0, 0])
+    for i, r in enumerate(rows):
+        one = np.asarray(step(r, toks[i:i + 1], pos[i:i + 1]))[0, 0]
+        np.testing.assert_allclose(one, logits[i], atol=1e-5)
+    budgets = [6, 4, 5, 3]
+    solo = [ContinuousBatchingEngine(model, params, n_slots=1, max_len=32, seed=0).generate(
+        [p], b)[0] for p, b in zip(prompts, budgets)]
+    together = ContinuousBatchingEngine(model, params, n_slots=3, max_len=32,
+                                        seed=0).generate(prompts, budgets)
+    for a, b in zip(solo, together):
         np.testing.assert_array_equal(a, b)
 
 
